@@ -559,9 +559,11 @@ def main(argv=None):
     from repro.core import (GNNConfig, GraphSAGE, build_pipeline,
                             build_train_step, load_dataset, train_loop)
     from repro.distributed.sharding import ShardingRules
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.launch.mesh import make_host_mesh
     from repro.optim import adamw
 
+    enable_compile_cache()
     if args.sampler == "saint":
         if args.backends != "host":
             print(f"bench_backends: --sampler saint is host-only; "

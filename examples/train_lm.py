@@ -2,16 +2,14 @@
 
 The exact same model/step/sharding code lowers the full configs on the
 512-chip production mesh in the dry-run; here we run a real optimization
-loop with checkpoint/auto-resume on a 2x1 CPU mesh.
+loop with checkpoint/auto-resume on a mesh of up to 2 devices (virtual
+host devices on the CPU).
 
 Run:  PYTHONPATH=src python examples/train_lm.py [arch] [steps]
 e.g.  PYTHONPATH=src python examples/train_lm.py mixtral-8x7b 30
 """
 
-import os
 import sys
-os.environ["XLA_FLAGS"] = os.environ.get("XLA_FLAGS", "") + \
-    " --xla_force_host_platform_device_count=2"
 
 import jax
 
@@ -28,7 +26,10 @@ steps = int(sys.argv[2]) if len(sys.argv) > 2 else 20
 
 cfg = get_config(arch).reduced()
 model = LM(cfg)
-mesh = make_mesh((2, 1), ("data", "model"))
+# 2 virtual devices when the platform is the CPU (the CPU backend alone
+# reads this); on an accelerator the mesh spans up to 2 real chips
+jax.config.update("jax_num_cpu_devices", 2)
+mesh = make_mesh((min(2, len(jax.devices())), 1), ("data", "model"))
 rules = ShardingRules.default()
 print(f"{cfg.name}: {model.param_count()/1e6:.2f}M params, family={cfg.family}")
 

@@ -25,11 +25,10 @@ from typing import Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core.partition import PartitionedGraph
-from repro.distributed.compat import shard_map
 from repro.core.sampler import DEFAULT_FANOUTS
 
 

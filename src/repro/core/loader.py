@@ -568,6 +568,7 @@ class PallasSubgraphLoader(_LoaderBase):
         import jax
         import jax.numpy as jnp
         from repro.kernels import ops
+        from repro.kernels.layout import to_rows
         self._devcache_bypass = False   # permanent once tripped
         self._bypass_events = 0
         self.indptr = jnp.asarray(g.indptr, jnp.int32)
@@ -581,6 +582,7 @@ class PallasSubgraphLoader(_LoaderBase):
         self._jax = jax
         fanouts_ = self.fanouts
         maxd = self.max_degree
+        self.feat_dim = width = g.feat_dim
 
         use_feat_cache = (device_cache is not None
                           and getattr(device_cache, "rows", 0))
@@ -609,7 +611,9 @@ class PallasSubgraphLoader(_LoaderBase):
                 policy=device_cache.policy,
                 pinned_fraction=device_cache.pinned_fraction)
         else:
-            self.features = jnp.asarray(g.features, jnp.float32)
+            # uploaded once in the kernels' (N, 1, Wp) row layout
+            self.features = jnp.asarray(
+                to_rows(np.asarray(g.features, np.float32)))
 
         if use_edge_cache:
             self._prepare = self._sample = None
@@ -629,7 +633,8 @@ class PallasSubgraphLoader(_LoaderBase):
                 hops = ops.sample_khop_kernel(indptr, indices, targets,
                                               fanouts_, key=key,
                                               max_degree=maxd)
-                hop_feats = [ops.feature_gather_rows(features, h)
+                hop_feats = [ops.feature_gather_rows(features, h,
+                                                     width=width)
                              for h in hops]
                 batch_labels = jnp.take(labels, targets)
                 return hops, hop_feats, batch_labels
@@ -802,8 +807,8 @@ class PallasSubgraphLoader(_LoaderBase):
                 hop_feats.append(jnp.take(rows, jnp.asarray(pos, jnp.int32),
                                           axis=0).reshape(h.shape + (F,)))
         else:
-            hop_feats = [self._ops.feature_gather_rows(self.features, h)
-                         for h in s["hops"]]
+            hop_feats = [self._ops.feature_gather_rows(
+                self.features, h, width=self.feat_dim) for h in s["hops"]]
         if s["ctx"] is not None:
             io = s["ctx"].counters()
         else:

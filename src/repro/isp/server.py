@@ -239,16 +239,25 @@ def run_server(config: dict) -> int:
     return 0
 
 
-def spawn_server(config: dict) -> subprocess.Popen:
-    """Launch ``python -m repro.isp.server`` with this interpreter and the
-    repo's source tree on the child's path."""
+def server_env() -> dict:
+    """The storage process's environment: the trainer's, with the repo's
+    source tree on the path and JAX pinned to the CPU — the storage
+    process is a host process and never claims the accelerator the
+    trainer holds (a chip belongs to one process at a time)."""
     import repro
     pkg = (os.path.dirname(repro.__file__) if getattr(repro, "__file__", None)
            else next(iter(repro.__path__)))       # namespace package
     src = os.path.dirname(os.path.abspath(pkg))
-    env = dict(os.environ)
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     env["PYTHONPATH"] = src + (os.pathsep + env["PYTHONPATH"]
                                if env.get("PYTHONPATH") else "")
+    return env
+
+
+def spawn_server(config: dict) -> subprocess.Popen:
+    """Launch ``python -m repro.isp.server`` with this interpreter, in
+    ``server_env()``."""
+    env = server_env()
     return subprocess.Popen(
         [sys.executable, "-m", "repro.isp.server",
          "--config", json.dumps(config)],

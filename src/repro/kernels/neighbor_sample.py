@@ -1,27 +1,30 @@
 """Pallas TPU kernel: in-storage-style neighbor sampling (paper Alg. 1).
 
 This is the ISP subgraph generator (Fig. 11) recast for the TPU memory
-hierarchy: the big neighbor edge-list array stays in HBM (the "flash")
-behind an ``ANY``-memory ref; for each target the kernel DMAs only the two
-consecutive *edge-list blocks* containing that target's neighbor list into
-a VMEM staging tile (the "SSD DRAM page buffer") — the block index is
-computed from the scalar-prefetched CSR offsets, exactly like the
-firmware's LBA->page translation (step ③) — then gathers the S sampled
-entries and emits the dense sampled-ID tensor (the "subgraph over PCIe").
+hierarchy: the big neighbor edge-list array stays in HBM (the "flash"),
+held as ``(blocks, 1, BLOCK_E)`` rows (``kernels.layout``); for each
+target the kernel DMAs only the two consecutive *edge-list blocks*
+containing that target's neighbor list into a VMEM staging tile (the
+"SSD DRAM page buffer") — the block index is computed from the
+scalar-prefetched CSR offsets, exactly like the firmware's LBA->page
+translation (step ③) — then gathers the S sampled entries and emits the
+dense sampled-ID tensor (the "subgraph over PCIe").
 
 HBM->VMEM traffic per target is 2 edge blocks (2*BLOCK_E*4 B) instead of
 the whole edge array — the kernel-level version of the paper's 20x
 transfer-amplification fix.
 
+One kernel serves both entry points: each block is read through a
+block -> slot table, which is the identity for the device-resident edge
+array (``neighbor_sample``) and the cache's indirection for the HBM
+edge-block cache (``neighbor_sample_cached``).
+
 Tiling: each grid step processes ``TILE_M`` targets (grid
 ``(ceil(M / TILE_M),)``), staging their edge blocks into a
-``(TILE_M, 2*BLOCK_E)`` VMEM tile and their CSR offsets/degrees into SMEM,
-then runs ONE vectorized iota-compare-reduce gather over the whole tile
-(the vectorizable TPU idiom for small dynamic gathers — no per-element
-dynamic addressing on the VPU).  The per-target edge-block transfers are
-unchanged; only grid dispatch is amortized, which is what removes the
-per-target interpreter/dispatch cost that dominated the one-target-per-
-program version.
+``(TILE_M, 1, 2*BLOCK_E)`` VMEM tile, then runs ONE vectorized
+iota-compare-reduce gather over the whole tile (the vectorizable TPU
+idiom for small dynamic gathers — no per-element dynamic addressing on
+the VPU).
 
 Requires max_degree <= BLOCK_E so a neighbor list spans at most two
 consecutive blocks (the staged pair covers lists that straddle a block
@@ -61,42 +64,82 @@ def edge_block_count(num_edges: int, block_e: int) -> int:
     return (num_edges + edge_pad(num_edges, block_e)) // block_e
 
 
-def _kernel(indptr_ref, targets_ref, rand_ref, edges_ref, out_ref,
-            blocks_ref, meta_ref, sem, *, block_e: int, tile_m: int,
-            max_base: int):
-    i = pl.program_id(0)
+def _kernel(indptr_ref, slots_ref, targets_ref, rand_ref, blocks_ref,
+            out_ref, staged_ref, sem, *, block_e: int, tile_m: int,
+            max_block: int):
+    row = jax.lax.broadcasted_iota(jnp.int32, (tile_m, 1), 0)
 
     def stage(j, carry):
-        t = targets_ref[i * tile_m + j]
+        off, deg, tgt = carry
+        t = targets_ref[0, 0, j]
         start = indptr_ref[t]
-        deg = indptr_ref[t + 1] - start
-        base = (start // block_e) * block_e        # LBA -> page translation
-        # a degree-0 offset at the array end would fetch past the pad; the
-        # clamp only ever binds for deg == 0 (whose output is the fallback)
-        base = jnp.minimum(base, max_base)
-        cp = pltpu.make_async_copy(edges_ref.at[pl.ds(base, 2 * block_e)],
-                                   blocks_ref.at[j], sem)
-        cp.start()
-        cp.wait()
-        meta_ref[0, j] = start - base
-        meta_ref[1, j] = deg
-        meta_ref[2, j] = t
-        return carry
+        d = indptr_ref[t + 1] - start
+        # LBA -> page translation; the clamp keeps the staged pair inside
+        # the padded array and only binds for degree-0 targets at the end
+        b = jnp.minimum(start // block_e, max_block)
+        for h in range(2):
+            # -1 = not resident; callers guarantee residency, the clamp
+            # only keeps a misuse from reading out of bounds
+            slot = jnp.maximum(slots_ref[b + h], 0)
+            cp = pltpu.make_async_copy(
+                blocks_ref.at[slot],
+                staged_ref.at[j, :, pl.ds(h * block_e, block_e)], sem)
+            cp.start()
+            cp.wait()
+        # SMEM holds scalars only: the per-target offset/degree/id are
+        # assembled into (TILE_M, 1) vectors row by row
+        here = row == j
+        return (jnp.where(here, start - b * block_e, off),
+                jnp.where(here, d, deg), jnp.where(here, t, tgt))
 
-    jax.lax.fori_loop(0, tile_m, stage, 0)
-
-    off = meta_ref[0, :]                           # (TILE_M,)
-    deg = meta_ref[1, :]
-    tgt = meta_ref[2, :]
-    blocks = blocks_ref[...]                       # (TILE_M, 2*BLOCK_E)
-    r = rand_ref[...] % jnp.maximum(deg[:, None], 1)          # (TILE_M, S)
-    local = off[:, None] + r                                  # (TILE_M, S)
+    zeros = jnp.zeros((tile_m, 1), jnp.int32)
+    off, deg, tgt = jax.lax.fori_loop(0, tile_m, stage, (zeros,) * 3)
+    blocks = staged_ref[...].reshape(tile_m, 2 * block_e)
+    r = rand_ref[...] % jnp.maximum(deg, 1)                   # (TILE_M, S)
+    local = off + r
     # tiled one-hot gather: picked[j, s] = blocks[j, local[j, s]]
     iota = jax.lax.broadcasted_iota(jnp.int32, (1, 1, 2 * block_e), 2)
     onehot = local[:, :, None] == iota
     picked = jnp.sum(jnp.where(onehot, blocks[:, None, :], 0), axis=2)
-    out_ref[...] = jnp.where(deg[:, None] > 0, picked,
-                             tgt[:, None]).astype(jnp.int32)
+    out_ref[...] = jnp.where(deg > 0, picked, tgt).astype(jnp.int32)
+
+
+def _sample_call(indptr, slots, targets, rand, blocks, *, block_e: int,
+                 tile_m: int, max_block: int, interpret: bool):
+    """Shared dispatch: M is padded up to a multiple of ``tile_m`` (pad
+    targets sample node 0, whose blocks are resident by contract, and
+    are sliced off), so tile boundaries never change results.  The CSR
+    offsets and the slot table are scalar-prefetched; the targets reach
+    SMEM one tile per grid step, so their count is not bounded by SMEM."""
+    M, S = rand.shape
+    m_pad = (-M) % tile_m
+    if m_pad:
+        targets = jnp.pad(targets, (0, m_pad))
+        rand = jnp.pad(rand, ((0, m_pad), (0, 0)))
+    n_tiles = (M + m_pad) // tile_m
+    kernel = functools.partial(_kernel, block_e=block_e, tile_m=tile_m,
+                               max_block=max_block)
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,              # indptr, slots
+            grid=(n_tiles,),
+            in_specs=[
+                pl.BlockSpec((1, 1, tile_m), lambda i, *_: (i, 0, 0),
+                             memory_space=pltpu.SMEM),            # targets
+                pl.BlockSpec((tile_m, S), lambda i, *_: (i, 0)),   # rand
+                pl.BlockSpec(memory_space=pltpu.HBM),  # blocks stay in HBM
+            ],
+            out_specs=pl.BlockSpec((tile_m, S), lambda i, *_: (i, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((tile_m, 1, 2 * block_e), jnp.int32),  # pairs
+                pltpu.SemaphoreType.DMA,
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((M + m_pad, S), jnp.int32),
+        interpret=interpret,
+    )(indptr, slots, targets.reshape(n_tiles, 1, tile_m), rand, blocks)
+    return out[:M]
 
 
 @functools.partial(jax.jit,
@@ -105,92 +148,20 @@ def neighbor_sample(indptr, indices, targets, rand, *, block_e: int = 512,
                     tile_m: int = TILE_M, interpret: bool = True):
     """indptr: (N+1,) int32; indices: (E,) int32; targets: (M,) int32;
     rand: (M, S) int32.  Returns (M, S) int32.  max degree must be
-    <= block_e (asserted by the ops wrapper).  M is padded up to a
-    multiple of ``tile_m`` (pad targets sample node 0, sliced off), so
-    tile boundaries never change results."""
-    M, S = rand.shape
+    <= block_e (asserted by the ops wrapper).  The edge array is padded
+    by ``edge_pad`` and viewed as ``(blocks, 1, block_e)`` rows (the
+    ``kernels.layout`` row layout), every block its own slot."""
     E = indices.shape[0]
-    m_pad = (-M) % tile_m
-    if m_pad:
-        targets = jnp.pad(targets, (0, m_pad))
-        rand = jnp.pad(rand, ((0, m_pad), (0, 0)))
-    M_pad = M + m_pad
-    # pad the edge array so the 2-block fetch never runs off the end: for
-    # deg > 0, base <= floor((E-1)/block_e)*block_e, so base + 2*block_e
-    # <= E_pad; degree-0 offsets at the array end are clamped in-kernel
-    pad = edge_pad(E, block_e)
-    indices = jnp.pad(indices, (0, pad))
-
-    kernel = functools.partial(_kernel, block_e=block_e, tile_m=tile_m,
-                               max_base=E + pad - 2 * block_e)
-    out = pl.pallas_call(
-        kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,              # indptr, targets
-            grid=(M_pad // tile_m,),
-            in_specs=[
-                pl.BlockSpec((tile_m, S), lambda i, *_: (i, 0)),   # rand
-                pl.BlockSpec(memory_space=pltpu.ANY),  # edges stay in HBM
-            ],
-            out_specs=pl.BlockSpec((tile_m, S), lambda i, *_: (i, 0)),
-            scratch_shapes=[
-                pltpu.VMEM((tile_m, 2 * block_e), jnp.int32),  # edge tiles
-                pltpu.SMEM((3, tile_m), jnp.int32),            # off/deg/tgt
-                pltpu.SemaphoreType.DMA,
-            ],
-        ),
-        out_shape=jax.ShapeDtypeStruct((M_pad, S), jnp.int32),
-        interpret=interpret,
-    )(indptr, targets, rand, indices)
-    return out[:M]
+    nb = edge_block_count(E, block_e)
+    blocks = jnp.pad(indices, (0, nb * block_e - E)).reshape(nb, 1, block_e)
+    return _sample_call(indptr, jnp.arange(nb, dtype=jnp.int32), targets,
+                        rand, blocks, block_e=block_e, tile_m=tile_m,
+                        max_block=nb - 2, interpret=interpret)
 
 
 # ---------------------------------------------------------------------------
 # cached variant: edge blocks come from an HBM block cache via indirection
 # ---------------------------------------------------------------------------
-
-def _cached_kernel(indptr_ref, slots_ref, targets_ref, rand_ref, cache_ref,
-                   out_ref, blocks_ref, meta_ref, sem, *, block_e: int,
-                   tile_m: int, max_block: int):
-    i = pl.program_id(0)
-
-    def stage(j, carry):
-        t = targets_ref[i * tile_m + j]
-        start = indptr_ref[t]
-        deg = indptr_ref[t + 1] - start
-        b = jnp.minimum(start // block_e, max_block)   # block-unit clamp:
-        # same bound as the uncached kernel's max_base (only binds for
-        # degree-0 targets at the array end)
-        s0 = jnp.maximum(slots_ref[b], 0)       # -1 = not resident; callers
-        s1 = jnp.maximum(slots_ref[b + 1], 0)   # guarantee residency, the
-        # clamp only keeps a misuse from reading out of bounds
-        cp0 = pltpu.make_async_copy(cache_ref.at[s0], blocks_ref.at[j, 0],
-                                    sem)
-        cp0.start()
-        cp0.wait()
-        cp1 = pltpu.make_async_copy(cache_ref.at[s1], blocks_ref.at[j, 1],
-                                    sem)
-        cp1.start()
-        cp1.wait()
-        meta_ref[0, j] = start - b * block_e
-        meta_ref[1, j] = deg
-        meta_ref[2, j] = t
-        return carry
-
-    jax.lax.fori_loop(0, tile_m, stage, 0)
-
-    off = meta_ref[0, :]
-    deg = meta_ref[1, :]
-    tgt = meta_ref[2, :]
-    blocks = blocks_ref[...].reshape(tile_m, 2 * block_e)
-    r = rand_ref[...] % jnp.maximum(deg[:, None], 1)
-    local = off[:, None] + r
-    iota = jax.lax.broadcasted_iota(jnp.int32, (1, 1, 2 * block_e), 2)
-    onehot = local[:, :, None] == iota
-    picked = jnp.sum(jnp.where(onehot, blocks[:, None, :], 0), axis=2)
-    out_ref[...] = jnp.where(deg[:, None] > 0, picked,
-                             tgt[:, None]).astype(jnp.int32)
-
 
 @functools.partial(jax.jit, static_argnames=("block_e", "tile_m",
                                              "max_block", "interpret"))
@@ -199,40 +170,14 @@ def neighbor_sample_cached(indptr, block_slots, targets, rand, cache, *,
                            tile_m: int = TILE_M, interpret: bool = True):
     """The out-of-core-topology version of ``neighbor_sample``: the edge
     array stays *off device* and each target's two consecutive edge blocks
-    are read from a ``(C, block_e)`` HBM block cache via the
+    are read from a ``(C, 1, block_e)`` HBM block cache via the
     ``block_slots`` (NB+1,) block-id -> slot indirection table (both
     scalar-prefetched, like the CSR offsets).  Every block a target
     dereferences must be resident (slot != -1) — the
     ``storage.devcache.DeviceEdgeBlockCache`` guarantees that by
     resolving the dispatch's planned block set first.  The staged pair's
     content equals the uncached kernel's two-block fetch, so sampled IDs
-    are bit-identical.  M is padded up to a ``tile_m`` multiple (pad
-    targets sample node 0, whose blocks (0, 1) are resident by the
-    planner's contract; pads are sliced off)."""
-    M, S = rand.shape
-    m_pad = (-M) % tile_m
-    if m_pad:
-        targets = jnp.pad(targets, (0, m_pad))
-        rand = jnp.pad(rand, ((0, m_pad), (0, 0)))
-    kernel = functools.partial(_cached_kernel, block_e=block_e,
-                               tile_m=tile_m, max_block=max_block)
-    out = pl.pallas_call(
-        kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,              # indptr, slots, targets
-            grid=((M + m_pad) // tile_m,),
-            in_specs=[
-                pl.BlockSpec((tile_m, S), lambda i, *_: (i, 0)),   # rand
-                pl.BlockSpec(memory_space=pltpu.ANY),  # cache stays in HBM
-            ],
-            out_specs=pl.BlockSpec((tile_m, S), lambda i, *_: (i, 0)),
-            scratch_shapes=[
-                pltpu.VMEM((tile_m, 2, block_e), jnp.int32),  # block pairs
-                pltpu.SMEM((3, tile_m), jnp.int32),           # off/deg/tgt
-                pltpu.SemaphoreType.DMA,
-            ],
-        ),
-        out_shape=jax.ShapeDtypeStruct((M + m_pad, S), jnp.int32),
-        interpret=interpret,
-    )(indptr, block_slots, targets, rand, cache)
-    return out[:M]
+    are bit-identical."""
+    return _sample_call(indptr, block_slots, targets, rand, cache,
+                        block_e=block_e, tile_m=tile_m, max_block=max_block,
+                        interpret=interpret)

@@ -1,20 +1,20 @@
 """Public jit'd wrappers for the Pallas kernels.
 
 Each op validates/pads shapes, picks hardware-aligned block sizes, and
-dispatches to the Pallas kernel — in interpret mode on CPU (this
-container) and compiled on real TPU.  ``use_kernels(False)`` or
-``REPRO_NO_KERNELS=1`` falls back to the jnp oracles, which is also what
-the 512-device dry-run lowers (kernels are a per-device compute detail,
-not a sharding one).
+dispatches to the Pallas kernel — compiled on the TPU, in interpret mode
+on the CPU (where the tests run), and refused on any other backend.
+``use_kernels(False)`` swaps in the jnp oracles, which is what the
+512-device dry-run lowers (kernels are a per-device compute detail, not
+a sharding one).
+
+Feature tables and both HBM caches are passed in the ``(n, 1, Wp)`` row
+layout of ``kernels.layout``; ``width`` is the logical row width.
 """
 
 from __future__ import annotations
 
-import os
-
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from repro.kernels import ref
 from repro.kernels.decode_attention import decode_attention as _decode_pl
@@ -22,12 +22,13 @@ from repro.kernels.flash_attention import flash_attention as _flash_pl
 from repro.kernels.feature_gather import feature_gather_cached as _cached_pl
 from repro.kernels.feature_gather import feature_gather_mean as _gather_pl
 from repro.kernels.feature_gather import feature_gather_rows as _rows_pl
+from repro.kernels.layout import from_rows
 from repro.kernels.neighbor_sample import neighbor_sample as _sample_pl
 from repro.kernels.neighbor_sample import \
     neighbor_sample_cached as _sample_cached_pl
 from repro.kernels.ssd_chunk_scan import ssd_chunk_scan as _ssd_pl
 
-_ENABLED = os.environ.get("REPRO_NO_KERNELS", "0") != "1"
+_ENABLED = True
 
 
 def use_kernels(enabled: bool) -> None:
@@ -35,15 +36,29 @@ def use_kernels(enabled: bool) -> None:
     _ENABLED = enabled
 
 
+def kernels_enabled() -> bool:
+    return _ENABLED
+
+
 def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    """Compile on the TPU, interpret on the CPU; no other backend has a
+    Pallas TPU kernel path, and none falls back silently."""
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise RuntimeError(f"Pallas TPU kernels compile on 'tpu' and are "
+                       f"interpreted on 'cpu'; backend {backend!r} has "
+                       "neither")
 
 
-def feature_gather_mean(table, ids):
-    """(N, F), (M, K) int32 -> (M, F) fanout-mean of gathered rows."""
+def feature_gather_mean(table, ids, *, width: int):
+    """(N, 1, Wp) row table, (M, K) int32 -> (M, width) fanout-mean of
+    gathered rows."""
     if not _ENABLED:
-        return ref.feature_gather_mean(table, ids)
-    return _gather_pl(table, ids, interpret=_interpret())
+        return ref.feature_gather_mean(from_rows(table, width), ids)
+    return _gather_pl(table, ids, width=width, interpret=_interpret())
 
 
 def edge_block_size(max_degree: int) -> int:
@@ -65,7 +80,7 @@ def neighbor_sample(indptr, indices, targets, rand, *, max_degree: int):
 def neighbor_sample_cached(indptr, cache, block_slots, targets, rand, *,
                            block_e: int, max_block: int):
     """Out-of-core-topology fanout sample: per-target edge blocks come
-    from the (C, block_e) HBM block cache through the ``block_slots``
+    from the (C, 1, block_e) HBM block cache through the ``block_slots``
     indirection table instead of the full device-resident edge array.
     Residency is the caller's contract (``DeviceEdgeBlockCache`` resolves
     the planned block set before dispatch); results are bit-identical to
@@ -73,8 +88,8 @@ def neighbor_sample_cached(indptr, cache, block_slots, targets, rand, *,
     if not _ENABLED:
         return ref.neighbor_sample_cached(
             indptr, block_slots, targets.astype(jnp.int32),
-            rand.astype(jnp.int32), cache, block_e=block_e,
-            max_block=max_block)
+            rand.astype(jnp.int32), from_rows(cache, block_e),
+            block_e=block_e, max_block=max_block)
     return _sample_cached_pl(indptr.astype(jnp.int32),
                              block_slots.astype(jnp.int32),
                              targets.astype(jnp.int32),
@@ -108,33 +123,34 @@ def sample_khop_kernel(indptr, indices, targets, fanouts, *, key,
     return hops
 
 
-def feature_gather_rows(table, ids):
-    """(N, F), ids (...,) int32 -> (..., F) row gather: ONE pallas_call per
-    hop tensor, staging TILE_ROWS rows per grid step."""
-    F = table.shape[1]
+def feature_gather_rows(table, ids, *, width: int):
+    """(N, 1, Wp) row table, ids (...,) int32 -> (..., width) row gather:
+    ONE pallas_call per hop tensor, staging TILE_ROWS rows per grid
+    step."""
     flat = ids.reshape(-1).astype(jnp.int32)
     if not _ENABLED:
-        out = ref.feature_gather_mean(table, flat[:, None])
+        out = ref.feature_gather_mean(from_rows(table, width), flat[:, None])
     else:
-        out = _rows_pl(table, flat, interpret=_interpret())
-    return out.reshape(ids.shape + (F,)).astype(table.dtype)
+        out = _rows_pl(table, flat, width=width, interpret=_interpret())
+    return out.reshape(ids.shape + (width,)).astype(table.dtype)
 
 
-def feature_gather_cached(cache, slot_of, ids):
-    """(C, F) HBM row cache, (N+1,) int32 slot table, ids (...,) int32 ->
-    (..., F): the device-cache read path — indirection lookup + tiled row
-    gather in one pallas_call.  Every id must be resident (slot != -1);
-    ``storage.devcache.DeviceFeatureCache`` guarantees that by resolving
-    misses before dispatch."""
-    F = cache.shape[1]
+def feature_gather_cached(cache, slot_of, ids, *, width: int):
+    """(C, 1, Wp) HBM row cache, (N+1,) int32 slot table, ids (...,) int32
+    -> (..., width): the device-cache read path — indirection lookup +
+    tiled row gather in one pallas_call.  Every id must be resident
+    (slot != -1); ``storage.devcache.DeviceFeatureCache`` guarantees that
+    by resolving misses before dispatch."""
     flat = ids.reshape(-1).astype(jnp.int32)
     if flat.shape[0] == 0:
-        return jnp.zeros(ids.shape + (F,), cache.dtype)
+        return jnp.zeros(ids.shape + (width,), cache.dtype)
     if not _ENABLED:
-        out = ref.feature_gather_cached(cache, slot_of, flat)
+        out = ref.feature_gather_cached(from_rows(cache, width), slot_of,
+                                        flat)
     else:
-        out = _cached_pl(cache, slot_of, flat, interpret=_interpret())
-    return out.reshape(ids.shape + (F,)).astype(cache.dtype)
+        out = _cached_pl(cache, slot_of, flat, width=width,
+                         interpret=_interpret())
+    return out.reshape(ids.shape + (width,)).astype(cache.dtype)
 
 
 def decode_attention(q, k, v, valid_len, window=0, *, block_s: int = 512):

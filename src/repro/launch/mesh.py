@@ -14,7 +14,8 @@ first jax import and only then builds the mesh.
 
 from __future__ import annotations
 
-from repro.distributed.compat import make_mesh as _compat_make_mesh
+import jax
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -27,12 +28,15 @@ def make_production_mesh(*, multi_pod: bool = False):
     """
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return _compat_make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_mesh(shape, axes):
-    """Generic mesh helper used by tests/examples (small CPU meshes)."""
-    return _compat_make_mesh(shape, axes)
+    """A mesh over the first prod(shape) devices.  The axes are Auto: the
+    installed JAX makes Explicit axes by default, and the sharding rules
+    here are constraints for Auto (GSPMD) propagation."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh():

@@ -28,6 +28,7 @@ def main():
     import jax.numpy as jnp
     import numpy as np
 
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.launch.mesh import make_host_mesh
     from repro.launch.shapes import make_batch
     from repro.distributed.sharding import ShardingRules
@@ -35,6 +36,7 @@ def main():
     from repro.models.transformer import LM
     from repro.train.steps import build_prefill_step, build_serve_step
 
+    enable_compile_cache()
     cfg = get_config(args.arch)
     if not args.full_config:
         cfg = cfg.reduced()

@@ -20,8 +20,7 @@ trajectory (tested in tests/test_train_integration.py).
 from __future__ import annotations
 
 import argparse
-import os
-import sys
+import math
 import time
 
 
@@ -43,7 +42,9 @@ def _parse():
     ap.add_argument("--reduced", action="store_true",
                     help="use the reduced same-family LM config (CPU)")
     ap.add_argument("--devices", type=int, default=1,
-                    help="CPU placeholder devices for the mesh")
+                    help="devices in the mesh: accelerator chips (the run "
+                         "fails if fewer exist), or virtual host devices "
+                         "when the platform is the CPU")
     ap.add_argument("--mesh", default=None,
                     help="mesh shape, e.g. 4x1 (default: devices x 1)")
     ap.add_argument("--ckpt-dir", default=None)
@@ -76,21 +77,26 @@ def _parse():
 
 def main():
     args = _parse()
-    if args.devices > 1:
-        os.environ["XLA_FLAGS"] = (
-            os.environ.get("XLA_FLAGS", "")
-            + f" --xla_force_host_platform_device_count={args.devices}")
 
     import jax
-    import jax.numpy as jnp
-    import numpy as np
 
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.launch.mesh import make_mesh
 
+    enable_compile_cache()
+    if args.devices > 1:
+        # read by the CPU backend only: on an accelerator the mesh is
+        # built from its real devices
+        jax.config.update("jax_num_cpu_devices", args.devices)
     if args.mesh:
         dims = tuple(int(x) for x in args.mesh.split("x"))
     else:
         dims = (args.devices, 1)
+    devices = jax.devices()
+    if len(devices) < math.prod(dims):
+        raise SystemExit(f"[train] mesh {dims} needs {math.prod(dims)} "
+                         f"devices; {devices[0].platform} has "
+                         f"{len(devices)}")
     mesh = make_mesh(dims, ("data", "model"))
 
     if args.arch == "graphsage":

@@ -23,11 +23,10 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.distributed.collectives import online_softmax_combine
-from repro.distributed.compat import shard_map
 
 NEG_INF = -1e30
 

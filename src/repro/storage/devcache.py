@@ -2,7 +2,8 @@
 page cache, one design instantiated per array family.
 
 ``DeviceArrayCache`` is the generic tier: a fixed-capacity ``(C, W)``
-HBM-resident entry cache plus a device-side ``entry_id -> slot``
+HBM-resident entry cache (held as ``(C, 1, Wp)`` rows, the layout of
+``kernels.layout``) plus a device-side ``entry_id -> slot``
 indirection table, with **host-managed, batched** admission/eviction —
 the LRU/pinned bookkeeping is vectorized numpy over whole id batches
 (stamp arrays + argpartition victim selection), not a per-id Python
@@ -55,6 +56,7 @@ import threading
 
 import numpy as np
 
+from repro.kernels.layout import row_width, to_rows
 from repro.obs import names as obs_names
 from repro.storage.specs import DEFAULT, DeviceCacheSpec
 
@@ -216,12 +218,14 @@ class DeviceArrayCache:
         # device state: +1 indirection entry — index n is the
         # scatter-padding sentinel, never queried by a real id
         self.slot_of = jnp.full((n + 1,), -1, jnp.int32)
-        self.table = jnp.zeros((self.capacity, W), dtype)
+        # the kernels' (C, 1, Wp) row layout; rows cross host->device at
+        # their logical width and are padded on device
+        self.table = jnp.zeros((self.capacity, 1, row_width(W)), dtype)
         donate = (0, 1) if jax.default_backend() == "tpu" else ()
 
         @functools.partial(jax.jit, donate_argnums=donate)
         def _update(table, slot_of, slots, rows, evict_ids, new_ids):
-            table = table.at[slots].set(rows)
+            table = table.at[slots].set(to_rows(rows))
             slot_of = slot_of.at[evict_ids].set(-1)
             slot_of = slot_of.at[new_ids].set(slots)
             return table, slot_of
@@ -587,8 +591,8 @@ class DeviceFeatureCache(DeviceArrayCache):
             n = ps.ids.size
             seg = pad_pow2(ps.ids, ps.ids[-1])
             parts.append(self._ops.feature_gather_cached(
-                self.table, self.slot_of,
-                jnp.asarray(seg, jnp.int32))[:n])
+                self.table, self.slot_of, jnp.asarray(seg, jnp.int32),
+                width=self.feat_dim)[:n])
         if not parts:
             return jnp.zeros((0, self.feat_dim), jnp.float32)
         return parts[0] if len(parts) == 1 else jnp.concatenate(parts, 0)

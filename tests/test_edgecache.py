@@ -48,7 +48,7 @@ def test_block_contents_match_padded_edge_array(small_graph):
     want_all[:g.num_edges] = g.indices
     for blocks in ([0, 1], [nb - 2, nb - 1], [3, 4, 5]):
         dc.resolve(np.asarray(blocks))
-        table = np.asarray(dc.table)
+        table = np.asarray(dc.table)[:, 0]      # (C, 1, block_e) rows
         slots = np.asarray(dc.slot_of)
         for b in blocks:
             np.testing.assert_array_equal(
@@ -196,7 +196,7 @@ def test_edgecache_misses_are_real_paged_reads(small_graph, disk_dir):
     io1 = st.io_counters()
     assert io1["block_fetches"] > io0["block_fetches"]
     # contents still exact through the paged path
-    table = np.asarray(dc.table)
+    table = np.asarray(dc.table)[:, 0]          # (C, 1, block_e) rows
     slots = np.asarray(dc.slot_of)
     np.testing.assert_array_equal(table[slots[0]],
                                   np.pad(g.indices[:block_e],

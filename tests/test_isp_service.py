@@ -564,6 +564,15 @@ def test_direct_io_default_off(disk_dir):
 # spec surface
 # ---------------------------------------------------------------------------
 
+def test_storage_process_env_pins_cpu(monkeypatch):
+    """The spawned storage process never claims the trainer's chip."""
+    from repro.isp.server import server_env
+    monkeypatch.setenv("JAX_PLATFORMS", "tpu")
+    env = server_env()
+    assert env["JAX_PLATFORMS"] == "cpu"
+    assert os.environ["JAX_PLATFORMS"] == "tpu"      # parent untouched
+
+
 def test_isp_spec_validation():
     with pytest.raises(ValueError, match="mode"):
         StoreSpec(kind="mem", mode="isp")

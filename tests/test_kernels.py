@@ -12,6 +12,7 @@ from repro.kernels import ops, ref
 from repro.kernels.decode_attention import decode_attention as decode_pl
 from repro.kernels.feature_gather import feature_gather_mean as gather_pl
 from repro.kernels.feature_gather import feature_gather_rows as rows_pl
+from repro.kernels.layout import to_rows
 from repro.kernels.neighbor_sample import neighbor_sample as sample_pl
 from repro.kernels.ssd_chunk_scan import ssd_chunk_scan as ssd_pl
 
@@ -27,12 +28,26 @@ def test_feature_gather_sweep(M, K, F, N, dtype):
     rng = np.random.default_rng(M * K)
     table = jnp.asarray(rng.standard_normal((N, F)), dtype)
     ids = jnp.asarray(rng.integers(0, N, (M, K)), jnp.int32)
-    out = gather_pl(table, ids)
+    out = gather_pl(to_rows(table), ids, width=F)
     expect = ref.feature_gather_mean(table, ids)
     tol = 1e-5 if dtype == jnp.float32 else 2e-2
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(expect, np.float32),
                                rtol=tol, atol=tol)
+
+
+@pytest.mark.parametrize("backend,interpret", [("cpu", True),
+                                               ("tpu", False),
+                                               ("gpu", None)])
+def test_interpret_only_on_cpu(monkeypatch, backend, interpret):
+    """Kernels compile on the TPU, are interpreted on the CPU, and no
+    other backend silently falls back to either."""
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    if interpret is None:
+        with pytest.raises(RuntimeError, match="'gpu'"):
+            ops._interpret()
+    else:
+        assert ops._interpret() is interpret
 
 
 # ---------------------------------------------------------------------------
@@ -120,12 +135,12 @@ def test_feature_gather_tile_boundaries(M, K, tile_m):
     rng = np.random.default_rng(M * 13 + K * 5 + tile_m)
     table = jnp.asarray(rng.standard_normal((96, 33)), jnp.float32)
     ids = jnp.asarray(rng.integers(0, 96, (M, K)), jnp.int32)
-    out = gather_pl(table, ids, tile_m=tile_m)
+    out = gather_pl(to_rows(table), ids, width=33, tile_m=tile_m)
     expect = ref.feature_gather_mean(table, ids)
     np.testing.assert_allclose(np.asarray(out), np.asarray(expect),
                                rtol=1e-5, atol=1e-5)
     flat = jnp.asarray(rng.integers(0, 96, M), jnp.int32)
-    rows = rows_pl(table, flat, tile_m=tile_m)
+    rows = rows_pl(to_rows(table), flat, width=33, tile_m=tile_m)
     np.testing.assert_array_equal(np.asarray(rows),
                                   np.asarray(table)[np.asarray(flat)])
 
@@ -166,14 +181,15 @@ def test_feature_gather_cached_tile_boundaries(R, tile_m):
     slot_of = np.full(N + 1, -1, np.int32)
     slot_of[:C] = rng.permutation(C)
     ids = jnp.asarray(rng.integers(0, C, R), jnp.int32)   # resident ids only
-    out = cached_pl(cache, jnp.asarray(slot_of), ids, tile_m=tile_m)
+    out = cached_pl(to_rows(cache), jnp.asarray(slot_of), ids, width=F,
+                    tile_m=tile_m)
     expect = np.asarray(cache)[slot_of[np.asarray(ids)]]
     np.testing.assert_array_equal(np.asarray(out), expect)
 
 
 def test_feature_gather_cached_ops_wrapper_nd():
     """ops.feature_gather_cached handles n-d hop tensors and matches the
-    jnp oracle (which is also the REPRO_NO_KERNELS fallback)."""
+    jnp oracle (which is also the ``use_kernels(False)`` fallback)."""
     rng = np.random.default_rng(6)
     N, C, F = 64, 16, 17
     cache = jnp.asarray(rng.standard_normal((C, F)), jnp.float32)
@@ -181,7 +197,8 @@ def test_feature_gather_cached_ops_wrapper_nd():
     resident = rng.choice(N, C, replace=False)
     slot_of[resident] = np.arange(C)
     ids = jnp.asarray(rng.choice(resident, (5, 3, 2)), jnp.int32)
-    out = ops.feature_gather_cached(cache, jnp.asarray(slot_of), ids)
+    out = ops.feature_gather_cached(to_rows(cache), jnp.asarray(slot_of),
+                                    ids, width=F)
     assert out.shape == (5, 3, 2, F)
     expect = ref.feature_gather_cached(cache, jnp.asarray(slot_of),
                                        np.asarray(ids).reshape(-1))
@@ -196,7 +213,7 @@ def test_feature_gather_rows_single_call_nd():
     rng = np.random.default_rng(3)
     table = jnp.asarray(rng.standard_normal((50, 17)), jnp.float32)
     ids = jnp.asarray(rng.integers(0, 50, (7, 3, 2)), jnp.int32)
-    out = ops.feature_gather_rows(table, ids)
+    out = ops.feature_gather_rows(to_rows(table), ids, width=17)
     assert out.shape == (7, 3, 2, 17)
     np.testing.assert_array_equal(np.asarray(out),
                                   np.asarray(table)[np.asarray(ids)])

@@ -83,7 +83,7 @@ import jax, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 from repro.launch.mesh import make_mesh
 from repro.roofline.hlo_parse import analyze
-from repro.distributed.compat import shard_map
+from jax import shard_map
 mesh = make_mesh((8,), ("x",))
 def f(a):
     return jax.lax.psum(a, "x")
