@@ -960,27 +960,30 @@ def train_loop(loader, train_step, state, *, steps: int, start: int = 0,
 
     ``on_step(i, state, metrics)`` is called after every step (logging,
     checkpointing).  Returns the final state and the run telemetry.
+    Each iteration is a ``train`` step marker (``obs.step_span``) in any
+    active profiler trace.
     """
     import jax
 
     stats = RunStats()
     t_start = time.perf_counter()
     for i in range(start, steps):
-        t0 = time.perf_counter()
-        with obs.trace_span("consume.wait", batch=i, lane="consumer"):
-            mb = loader.get_batch(i)
-        t1 = time.perf_counter()
-        with obs.trace_span("consume.step", batch=i, lane="consumer"):
-            state, metrics = train_step(state, mb)
-            # async dispatch would otherwise push device compute into the
-            # next step's idle window and skew the idle/busy split
-            jax.block_until_ready(metrics)
-        t2 = time.perf_counter()
-        stats.idle_s += t1 - t0
-        stats.busy_s += t2 - t1
-        stats.steps += 1
-        obs.tick()                   # periodic JSONL metrics snapshot
-        if on_step is not None:
-            on_step(i, state, metrics)
+        with obs.step_span("train", i):
+            t0 = time.perf_counter()
+            with obs.trace_span("consume.wait", batch=i, lane="consumer"):
+                mb = loader.get_batch(i)
+            t1 = time.perf_counter()
+            with obs.trace_span("consume.step", batch=i, lane="consumer"):
+                state, metrics = train_step(state, mb)
+                # async dispatch would otherwise push device compute into
+                # the next step's idle window and skew the idle/busy split
+                jax.block_until_ready(metrics)
+            t2 = time.perf_counter()
+            stats.idle_s += t1 - t0
+            stats.busy_s += t2 - t1
+            stats.steps += 1
+            obs.tick()               # periodic JSONL metrics snapshot
+            if on_step is not None:
+                on_step(i, state, metrics)
     stats.wall_s = time.perf_counter() - t_start
     return state, stats

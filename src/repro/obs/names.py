@@ -13,9 +13,7 @@ single-source-of-truth violation rather than a latent rename.
 
 ``flatten_stats`` maps a loader ``stats()`` tree onto the canonical
 flat namespace (the shape the metrics registry snapshots and BENCH rows
-embed), and ``legacy_key`` is the compat shim: it answers which
-pre-unification key an old BENCH comparison script would have used for
-a canonical name, so historical BENCH JSONs stay comparable.
+embed).
 """
 
 from __future__ import annotations
@@ -84,40 +82,6 @@ CANONICAL_NAMES: dict[str, tuple[str, ...]] = {
                 + ("pipeline.degraded",),
     "train": tuple(canonical("train", k) for k in TRAIN_KEYS),
 }
-
-# -- compat shim -------------------------------------------------------------
-
-# canonical name -> the key an old BENCH/stats consumer read.  Before
-# unification the fault kinds sat *flat* inside the store block
-# (``loader_stats["store"]["retries"]``) and trace assembly nested them
-# under ``io["faults"]``; both spellings map onto ``store.faults.*``.
-_LEGACY: dict[str, str] = {}
-for _k in STORE_IO_KEYS:
-    _LEGACY[f"store.{_k}"] = _k
-for _k in FAULT_KEYS:
-    _LEGACY[f"store.faults.{_k}"] = _k
-for _t in TIERS:
-    for _k in DEVCACHE_KEYS:
-        _LEGACY[f"{_t}.{_k}"] = _k
-for _k in ORACLE_KEYS:
-    _LEGACY[f"oracle.{_k}"] = _k
-for _k in PIPELINE_KEYS:
-    _LEGACY[f"pipeline.{_k}"] = _k
-
-
-def legacy_key(name: str) -> str | None:
-    """The pre-unification flat key for a canonical metric name (the
-    key inside its old ``stats()`` subtree), or ``None`` when the metric
-    did not exist before the unified layer (e.g. ``store.hit_rate``)."""
-    return _LEGACY.get(name)
-
-
-def from_legacy(group: str, key: str) -> str:
-    """Map an old-style ``(subtree, flat key)`` pair onto its canonical
-    name — the direction BENCH comparison scripts need when they hold a
-    historical row and want to look up the same counter in a new one."""
-    return canonical(group, key)
-
 
 # -- stats-tree flattening ---------------------------------------------------
 

@@ -1,9 +1,14 @@
 """Unified telemetry layer: registry thread-safety, stable histogram
 buckets, closed/ordered spans in the Perfetto export, associativity of
-snapshot merging, canonical-name mapping, and an end-to-end pipeline
-run proving telemetry files are produced without perturbing bits."""
+snapshot merging, canonical-name mapping, an end-to-end pipeline run
+proving telemetry files are produced without perturbing bits, and the
+same spans and step markers in a ``jax.profiler`` trace."""
 
+import collections
 import json
+import os
+import subprocess
+import sys
 import threading
 
 import pytest
@@ -200,15 +205,6 @@ def test_canonical_names_single_source():
         "devcache.bytes_uploaded"
 
 
-def test_legacy_key_compat_shim():
-    """Old BENCH comparison keys are recoverable from canonical names."""
-    assert names.legacy_key("store.faults.retries") == "retries"
-    assert names.legacy_key("devcache.hits") == "hits"
-    assert names.legacy_key("store.hit_rate") is None   # new metric
-    assert names.from_legacy("store", "io_errors") == \
-        "store.faults.io_errors"
-
-
 def test_flatten_stats_maps_tree_to_canonical():
     stats = {
         "store": {"requests": 10, "block_fetches": 4, "bytes_fetched": 8192,
@@ -264,32 +260,37 @@ def _run_spec(spec, g, steps=4):
     return losses
 
 
+def _disk_spec(store_dir, obs_spec=None):
+    """A disk-backed overlapped pallas pipeline with a device row cache:
+    every lane, the devcache stages and the store's preads run."""
+    from repro.core.config import (BackendSpec, CacheTierSpec, ObsSpec,
+                                   PipelineSpec, PrefetchSpec, StoreSpec)
+    return PipelineSpec(
+        backend=BackendSpec(name="pallas"),
+        store=StoreSpec(kind="disk", path=str(store_dir), io_threads=2),
+        cache_tiers=(
+            CacheTierSpec(tier="host", policy="lru", capacity_mb=0.5,
+                          arrays=()),
+            CacheTierSpec.device(rows=48, policy="lru")),
+        prefetch=PrefetchSpec(depth=2, overlap=True, stage_depth=2),
+        batch_size=8, obs=obs_spec or ObsSpec())
+
+
 def test_pipeline_telemetry_end_to_end(small_graph, tmp_path):
     """A disk-backed pallas+devcache run with telemetry on writes a
     Perfetto-loadable trace (pipeline/disk spans attributed to batches)
     and JSONL snapshots with the per-tier counters — and its loss
     trajectory is repr-identical to the telemetry-off twin."""
-    from repro.core.config import (BackendSpec, CacheTierSpec, ObsSpec,
-                                   PipelineSpec, PrefetchSpec, StoreSpec)
+    from repro.core.config import ObsSpec
     trace_path = tmp_path / "trace.json"
     metrics_path = tmp_path / "metrics.jsonl"
 
-    def spec(obs_spec):
-        return PipelineSpec(
-            backend=BackendSpec(name="pallas"),
-            store=StoreSpec(kind="disk", path=str(tmp_path / "gs"),
-                            io_threads=2),
-            cache_tiers=(
-                CacheTierSpec(tier="host", policy="lru", capacity_mb=0.5,
-                              arrays=()),
-                CacheTierSpec.device(rows=48, policy="lru")),
-            prefetch=PrefetchSpec(depth=2, overlap=True, stage_depth=2),
-            batch_size=8, obs=obs_spec)
-
-    on = _run_spec(spec(ObsSpec(trace_path=str(trace_path),
-                                metrics_path=str(metrics_path),
-                                metrics_interval_s=0.05)), small_graph)
-    off = _run_spec(spec(ObsSpec()), small_graph)
+    on = _run_spec(_disk_spec(tmp_path / "gs",
+                              ObsSpec(trace_path=str(trace_path),
+                                      metrics_path=str(metrics_path),
+                                      metrics_interval_s=0.05)),
+                   small_graph)
+    off = _run_spec(_disk_spec(tmp_path / "gs"), small_graph)
     assert [repr(x) for x in on] == [repr(x) for x in off]
 
     trace = json.loads(trace_path.read_text())
@@ -317,3 +318,152 @@ def test_pipeline_telemetry_end_to_end(small_graph, tmp_path):
               "store.faults.retries"):
         assert k in snap, (k, sorted(snap))
     assert snap["store.bytes_fetched"] > 0
+
+
+# ---------------------------------------------------------------------------
+# the profiler sink: the same spans, and step markers, in a jax trace
+# ---------------------------------------------------------------------------
+
+PROFILED_STEPS = 4
+
+
+def _tracereduce():
+    bench = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "bench")
+    if bench not in sys.path:
+        sys.path.insert(0, bench)
+    import tracereduce
+    return tracereduce
+
+
+def _profile(trace_dir, fn):
+    """``fn()`` under ``jax.profiler`` (Python calls untraced, as the
+    benchmark's traced run has it)."""
+    import jax
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(str(trace_dir), profiler_options=options)
+    try:
+        return fn()
+    finally:
+        jax.profiler.stop_trace()
+
+
+def _host_events(trace_dir):
+    """(name, stats) of every host-plane event of the profile."""
+    import glob
+
+    import jax
+    path, = glob.glob(os.path.join(str(trace_dir), "**", "*.xplane.pb"),
+                      recursive=True)
+    pd = jax.profiler.ProfileData.from_file(path)
+    return [(ev.name, dict(ev.stats)) for plane in pd.planes
+            if plane.name.startswith("/host:")
+            for line in plane.lines for ev in line.events]
+
+
+@pytest.fixture(scope="module")
+def profiled_run(small_graph, tmp_path_factory):
+    """The disk-backed overlapped run under the profiler, with no
+    ``ObsSession`` installed: (losses, profile directory)."""
+    tmp = tmp_path_factory.mktemp("profiled")
+    assert obs.active_session() is None
+    losses = _profile(tmp / "trace", lambda: _run_spec(
+        _disk_spec(tmp / "gs"), small_graph, steps=PROFILED_STEPS))
+    return losses, tmp / "trace"
+
+
+def test_profiler_trace_holds_the_program_spans(profiled_run):
+    """With no session, every lane stage, store read group and consumer
+    span and a step marker per step land on the profile's host plane,
+    where the benchmark's reduction reads them: one stage span per
+    batch, and read groups attributed to the batch they were issued
+    for.  The per-block preads stay off the profile."""
+    _, trace_dir = profiled_run
+    tr = _tracereduce().load(str(trace_dir))
+    count = collections.Counter(s.name for s in tr.spans)
+    for name in ("sample", "resolve", "admit", "disk.read_group",
+                 "consume.wait", "consume.step", "train"):
+        assert count[name], f"no {name} spans in {sorted(count)}"
+    assert not count["disk.pread"]
+    for name in ("consume.wait", "consume.step", "train"):
+        assert count[name] == PROFILED_STEPS, (name, count[name])
+    events = _host_events(trace_dir)
+    assert sorted(st["step_num"] for n, st in events if n == "train") == \
+        list(range(PROFILED_STEPS))
+    for stage in ("sample", "resolve", "admit"):
+        batches = [st["batch"] for n, st in events if n == stage]
+        assert len(batches) == len(set(batches)), (stage, batches)
+        assert set(range(PROFILED_STEPS)) <= set(batches), (stage, batches)
+    groups = [st for n, st in events if n == "disk.read_group"]
+    assert all({"array", "ranges"} <= set(st) for st in groups)
+    assert any("batch" in st for st in groups)
+
+
+def test_profiled_run_is_bit_identical(profiled_run, small_graph,
+                                       tmp_path):
+    """Spans only observe the clock: the loss trajectory under the
+    profiler is repr-identical to the same run without it."""
+    on, _ = profiled_run
+    off = _run_spec(_disk_spec(tmp_path / "gs"), small_graph,
+                    steps=PROFILED_STEPS)
+    assert [repr(x) for x in on] == [repr(x) for x in off]
+
+
+def test_trace_span_fast_path_with_jax_and_no_profiler():
+    """With jax imported but no profiler session and no ObsSession, the
+    hooks hand back the shared null span and report tracing off."""
+    import jax
+    assert not jax.profiler.TraceAnnotation.is_enabled()
+    assert obs.active_session() is None
+    assert obs.trace_span("disk.read_group", ranges=3, batch=None) is \
+        obs.NULL_SPAN
+    assert obs.session_span("disk.pread", block=3) is obs.NULL_SPAN
+    assert obs.step_span("train", 0) is obs.NULL_SPAN
+    assert not obs.tracing()
+
+
+def test_obs_stays_importable_without_jax():
+    """``obs`` resolves the profiler hook lazily: a process that never
+    imports jax gets the null span and never loads jax through it."""
+    code = ("import sys\n"
+            "from repro import obs\n"
+            "assert obs.trace_span('x', batch=1) is obs.NULL_SPAN\n"
+            "assert obs.step_span('train', 0) is obs.NULL_SPAN\n"
+            "assert not obs.tracing()\n"
+            "assert 'jax' not in sys.modules, 'obs imported jax'\n")
+    src = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "src")
+    env = dict(os.environ, PYTHONPATH=src)
+    r = subprocess.run([sys.executable, "-c", code], env=env,
+                       capture_output=True, text=True, timeout=120)
+    assert r.returncode == 0, r.stderr
+
+
+def test_span_reaches_profiler_and_session_together(tmp_path):
+    """With a profiler and a session both on, one ``trace_span`` is an
+    event of the profile (its ``None`` attributes and ``lane`` left
+    out) and a span of the session's Perfetto export (on its lane); a
+    ``session_span`` is the export's alone."""
+    s = obs.install(obs.ObsSession(trace_path=str(tmp_path / "t.json")))
+
+    def body():
+        assert obs.tracing()
+        with obs.trace_span("consume.wait", batch=5, block=None,
+                            lane="consumer"):
+            with obs.session_span("disk.pread", block=9, lane="consumer"):
+                pass
+    try:
+        _profile(tmp_path / "trace", body)
+    finally:
+        s.close()
+    events = [(n, st) for n, st in _host_events(tmp_path / "trace")
+              if n in ("consume.wait", "disk.pread")]
+    assert events == [("consume.wait", {"batch": 5})]
+    trace = json.loads((tmp_path / "t.json").read_text())
+    xs = [e for e in trace["traceEvents"] if e["ph"] == "X"]
+    assert sorted((e["name"], e["args"]) for e in xs) == \
+        [("consume.wait", {"batch": 5}), ("disk.pread", {"block": 9})]
+    lanes = [m["args"]["name"] for m in trace["traceEvents"]
+             if m["ph"] == "M"]
+    assert lanes == ["consumer"]
