@@ -22,7 +22,7 @@ from __future__ import annotations
 
 #: ``DiskStore`` per-context I/O bill (``IOContext``/``io_counters``).
 STORE_IO_KEYS = ("requests", "block_fetches", "bytes_fetched", "hits",
-                 "misses", "evictions")
+                 "misses", "evictions", "preads")
 
 #: Fault kinds, flat — ``nest_fault_counters`` folds them under
 #: ``"faults"`` at trace-assembly time; canonically they live under

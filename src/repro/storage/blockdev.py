@@ -23,6 +23,11 @@ from repro.core.graph import CSRGraph
 
 EDGE_ENTRY_BYTES = 8    # the paper's 8-byte neighbor entries (§III-B)
 
+#: The payload of a live-cache slot that ``LRUCache.lookup_run`` claimed
+#: for a block still being read: a miss to every ``lookup_run`` until
+#: ``fill``.  (Stores that read through ``get``/``put`` never reserve.)
+RESERVED = object()
+
 
 @dataclasses.dataclass
 class BlockTrace:
@@ -133,6 +138,55 @@ class LRUCache:
             self.evictions += 1
             return evicted
         return None
+
+    # -- batched live-cache path (``DiskStore``'s block-batched reads) -------
+    def lookup_run(self, blocks) -> tuple[list, list, list, int]:
+        """Touch distinct ``blocks`` in order, as ``access`` would one at a
+        time, for a reader that fetches its misses afterwards: a resident
+        block is a hit; a missing one counts as a miss and takes its slot
+        at once, ``RESERVED`` (evicting the LRU block as ``put`` would)
+        until ``fill`` stores its payload.  A slot another reader reserved
+        and has not filled yet is a miss as well (touched, not
+        re-inserted).  Returns the hits' positions in ``blocks`` and
+        payloads, the misses' positions, and the number of evictions."""
+        od = self._od
+        cap = self.capacity
+        hit_at, found, miss_at = [], [], []
+        evictions = 0
+        for k, b in enumerate(blocks):
+            data = od.get(b)
+            if data is None:
+                miss_at.append(k)
+                od[b] = RESERVED
+                if len(od) > cap:
+                    od.popitem(last=False)
+                    evictions += 1
+            else:
+                od.move_to_end(b)
+                if data is RESERVED:
+                    miss_at.append(k)
+                else:
+                    hit_at.append(k)
+                    found.append(data)
+        self.hits += len(hit_at)
+        self.misses += len(miss_at)
+        self.evictions += evictions
+        return hit_at, found, miss_at, evictions
+
+    def fill(self, blocks, payloads) -> None:
+        """Store fetched payloads in the slots ``lookup_run`` reserved
+        for them, where still reserved, without touching recency."""
+        od = self._od
+        for b, p in zip(blocks, payloads):
+            if od.get(b) is RESERVED:
+                od[b] = p
+
+    def release(self, blocks) -> None:
+        """Drop the still-reserved slots of ``blocks`` (a failed read)."""
+        od = self._od
+        for b in blocks:
+            if od.get(b) is RESERVED:
+                del od[b]
 
     def counters(self) -> dict:
         return {"hits": self.hits, "misses": self.misses,

@@ -309,6 +309,16 @@ class DiskStore:
     pool read bills the ``IOContext`` installed on the thread that
     triggered it (``io_attribution``), which is what makes per-batch
     ``SampleTrace.io`` deltas exact under concurrent producers.
+
+    Reads go block-batched (``_read_blocks``): a group of ranges (one
+    range, a gather's pool task or serial read, a ``warm_nodes`` task)
+    looks up its distinct blocks with one lock acquisition per shard,
+    reads the missed ones as contiguous runs (one ``pread`` per run; the
+    ``preads`` counter against ``block_fetches`` says how often runs
+    coalesce) and bills the group once.  A store with a fault injector,
+    ``direct_io`` or ``policy='optimal'`` reads block by block instead
+    (``_read_range``): the injector and O_DIRECT act on single blocks,
+    and the Belady cache has no batched lookup.
     """
 
     kind = "disk"
@@ -349,7 +359,9 @@ class DiskStore:
                     "re-save it with save_graph() or open with verify=False")
             self._crc = {k: np.asarray(a["block_crc32c"], np.uint32)
                          for k, a in self.manifest["arrays"].items()}
-        self._fault_totals = dict.fromkeys(IOContext.FAULT_KEYS, 0)
+        # store totals of the counters ``_bill`` adds: syscalls and faults
+        self._read_totals = dict.fromkeys(("preads",) + IOContext.FAULT_KEYS,
+                                          0)
         self.cache_mb = (spec.diskstore.cache_mb if cache_mb is None
                          else float(cache_mb))
         self.policy = policy or spec.diskstore.policy
@@ -364,6 +376,8 @@ class DiskStore:
                        for k, a in self._arrays.items()}
         self._tls = threading.local()
         self._open_backing_files(direct_io)
+        self._batched = (self._injector is None and not self.direct_io
+                         and self.policy != "optimal")
 
         # the CSR row index stays resident — it IS the index structure
         # (N+1 int64: a few MB even at the paper's billion-edge scale)
@@ -553,27 +567,71 @@ class DiskStore:
         return os.pread(self._fd[key], self.block_bytes,
                         block * self.block_bytes)
 
-    def _verify_block(self, key: str, block: int, data: bytes) -> bool:
-        if self._crc is None:
-            return True
-        return crc32c(data) == int(self._crc[key][block])
-
-    def _count_faults(self, faults: dict) -> None:
-        self._current_ctx().add(**faults)
+    def _bill(self, counts: dict) -> None:
+        """Add ``preads`` and fault counts to the caller's context and
+        the store totals."""
+        self._current_ctx().add(**counts)
         with self._stat_lock:
-            for k, v in faults.items():
-                self._fault_totals[k] += v
+            for k, v in counts.items():
+                self._read_totals[k] += v
+
+    def _attempt(self, key: str, block: int, n: int, attempt: int,
+                 spans: bool, batch
+                 ) -> tuple[bytes | None, str | None, Exception | None]:
+        """One ``pread`` of the ``n`` blocks from ``block`` and its checks:
+        ``(data, None, None)``, or ``(None, kind, error)`` for an attempt
+        that raised OSError (``io_errors``), came back short
+        (``short_reads``), failed a block's checksum (``corrupt_blocks``,
+        with ``verify``) or ran past ``retry.deadline_s`` a block
+        (``timeouts``).  The fault injector and O_DIRECT act on single
+        blocks (``n == 1``).  With ``spans`` (``obs.tracing()``, resolved
+        once by the caller) the read is a ``disk.pread``/``disk.retry``
+        session span for ``batch``."""
+        B = self.block_bytes
+        t0 = time.perf_counter()
+        try:
+            with (obs_session.session_span(
+                    "disk.pread" if attempt == 0 else "disk.retry",
+                    array=key, block=int(block), blocks=n, attempt=attempt,
+                    batch=batch) if spans else obs_session.NULL_SPAN):
+                if n > 1:
+                    data = os.pread(self._fd[key], n * B, block * B)
+                elif self._injector is not None:
+                    data = self._injector.read(
+                        lambda: self._read_block_raw(key, block),
+                        key, block, attempt)
+                else:
+                    data = self._read_block_raw(key, block)
+        except OSError as e:
+            return None, "io_errors", e
+        if len(data) != n * B:
+            return None, "short_reads", StoreReadError(
+                f"{key} block {block}: short read ({len(data)}/{n * B} "
+                "bytes)")
+        if self._crc is not None:
+            crc = self._crc[key]
+            mv = memoryview(data)
+            for k in range(n):
+                if crc32c(mv[k * B:(k + 1) * B]) != int(crc[block + k]):
+                    return None, "corrupt_blocks", StoreReadError(
+                        f"{key} block {block + k}: CRC32C mismatch")
+        if time.perf_counter() - t0 > self.retry.deadline_s * n:
+            return None, "timeouts", StoreReadError(
+                f"{key} block {block}: read exceeded the "
+                f"{self.retry.deadline_s}s deadline")
+        return data, None, None
 
     def _fetch(self, key: str, block: int) -> bytes:
-        """One block read under the retry policy.  Every path into disk
-        funnels here — ``_read_range`` (and through it the ``io_threads``
-        pool groups and the planner warms) and the pinned preload — so
-        the policy covers the entire pread surface.  An attempt fails on
-        OSError, a short return, a checksum mismatch (``verify``), or by
-        running past ``retry.deadline_s``; failures are retried with
+        """One block read under the retry policy.  Every per-block path
+        into disk funnels here — ``_read_range``, the pinned preload, and
+        any run the block-batched reads (``_read_runs``) fail to read
+        whole — so the policy covers the entire pread surface.  Each
+        attempt is one ``_attempt``; failures are retried with
         deterministic-jitter backoff up to ``retry.max_attempts`` total
-        tries, then raise ``StoreReadError``.  Fault counters bill the
-        caller's ``IOContext`` (flat keys) plus the store totals.
+        tries, then raise ``StoreReadError``.  Fault counters, and the
+        ``preads`` of attempts after the first (the caller counts one per
+        call), bill the caller's ``IOContext`` (flat keys) plus the store
+        totals.
         (The resident ``indptr`` load at open is the one read outside
         this path: it fails loudly at construction, nothing to retry
         into.)"""
@@ -587,52 +645,25 @@ class DiskStore:
         # group (``_read_group``) stands for them, since an annotation
         # per block slowed the out-of-core loader by 12.6-15.0% (TPU
         # v5e host)
-        span_batch = (self._current_ctx().batch
-                      if obs_session.tracing() else None)
+        spans = obs_session.tracing()
+        span_batch = self._current_ctx().batch if spans else None
 
         def note(kind):
             faults[kind] = faults.get(kind, 0) + 1
 
         for attempt in range(r.max_attempts):
-            t0 = time.perf_counter()
-            data = None
-            try:
-                with obs_session.session_span(
-                        "disk.pread" if attempt == 0 else "disk.retry",
-                        array=key, block=int(block), attempt=attempt,
-                        batch=span_batch):
-                    if self._injector is not None:
-                        data = self._injector.read(
-                            lambda: self._read_block_raw(key, block),
-                            key, block, attempt)
-                    else:
-                        data = self._read_block_raw(key, block)
-            except OSError as e:
-                last = e
-                note("io_errors")
-            if data is not None:
-                if len(data) != self.block_bytes:
-                    last = StoreReadError(
-                        f"{key} block {block}: short read "
-                        f"({len(data)}/{self.block_bytes} bytes)")
-                    note("short_reads")
-                elif not self._verify_block(key, block, data):
-                    last = StoreReadError(
-                        f"{key} block {block}: CRC32C mismatch")
-                    note("corrupt_blocks")
-                elif time.perf_counter() - t0 > r.deadline_s:
-                    last = StoreReadError(
-                        f"{key} block {block}: read exceeded the "
-                        f"{r.deadline_s}s deadline")
-                    note("timeouts")
-                else:
-                    if faults:
-                        self._count_faults(faults)
-                    return data
+            data, kind, last = self._attempt(key, block, 1, attempt, spans,
+                                             span_batch)
+            if kind is None:
+                if faults:
+                    self._bill(faults)
+                return data
+            note(kind)
             if attempt + 1 < r.max_attempts:
                 note("retries")
+                note("preads")
                 time.sleep(r.backoff(key, block, attempt))
-        self._count_faults(faults)
+        self._bill(faults)
         raise StoreReadError(
             f"{key} block {block}: read failed after {r.max_attempts} "
             f"attempt(s): {last}") from last
@@ -685,9 +716,10 @@ class DiskStore:
         return self._pool.submit(run)
 
     def _read_range(self, key: str, lo: int, hi: int) -> bytes:
-        """Bytes [lo, hi) of array ``key``, block-granular via the cache.
-        Each block locks only its hash shard, so concurrent producers
-        reading different blocks proceed in parallel."""
+        """Bytes [lo, hi) of array ``key``, block by block via the cache,
+        for a store that does not batch (see the class docstring).  Each
+        block locks only its hash shard, so concurrent producers reading
+        different blocks proceed in parallel."""
         if hi <= lo:
             return b""
         B = self.block_bytes
@@ -729,21 +761,164 @@ class DiskStore:
             self._block_fetches += misses
             self._bytes_fetched += nbytes
             self._pinned_hits += pinned_hits
+            self._read_totals["preads"] += misses
         # attribution context: exact per-scope (per-batch) deltas, even
         # when this read runs on a pool thread for another thread's batch
         self._current_ctx().add(
             requests=1, hits=hits + pinned_hits, misses=misses,
-            block_fetches=misses, bytes_fetched=nbytes, evictions=evictions)
+            block_fetches=misses, bytes_fetched=nbytes, evictions=evictions,
+            preads=misses)
         buf = parts[0] if len(parts) == 1 else b"".join(parts)
         off = lo - first * B
         return buf[off:off + (hi - lo)]
 
+    def _read_blocks(self, key: str, los: np.ndarray, his: np.ndarray
+                     ) -> tuple[np.ndarray, np.ndarray]:
+        """Ranges [los, his) of array ``key`` as one uint8 buffer and each
+        range's start in it (empty ranges start at 0).  The buffer holds
+        the distinct blocks the ranges cover, ascending
+        (``_read_block_list``), so each range is contiguous in it and a
+        block two ranges share is looked up once."""
+        B = self.block_bytes
+        nz = his > los
+        first, last = los[nz] // B, (his[nz] - 1) // B
+        counts = last - first + 1
+        cum = np.cumsum(counts)
+        blk = np.unique(np.repeat(first - cum + counts, counts)
+                        + np.arange(int(cum[-1]) if cum.size else 0))
+        starts = np.zeros(los.size, np.int64)
+        starts[nz] = np.searchsorted(blk, first) * B + los[nz] - first * B
+        return self._read_block_list(key, blk.tolist(), counts.size), starts
+
+    def _read_block_list(self, key: str, blocks: list, requests: int
+                         ) -> np.ndarray:
+        """Distinct ascending ``blocks`` of array ``key``, read for
+        ``requests`` ranges, as one uint8 buffer filled through the page
+        cache:
+
+        1. one locked ``LRUCache.lookup_run`` per shard, which touches the
+           blocks in order as one-at-a-time reads would and reserves the
+           slots of the misses;
+        2. the misses read as contiguous runs, one ``pread`` each
+           (``_read_runs``);
+        3. the fetched blocks stored in their reserved slots;
+        4. one ``IOContext.add`` and one ``_stat_lock`` section.
+
+        Counters match a sequential replay of the blocks."""
+        B = self.block_bytes
+        buf = np.empty(len(blocks) * B, np.uint8)
+        if not blocks:
+            return buf
+        mv = memoryview(buf)
+        ns = self._ns[key] * _NS_STRIDE
+        S = self.lock_shards
+        pinned = self._pinned
+        pinned_hits = 0
+        by_shard: dict[int, list[int]] = {}
+        for p, b in enumerate(blocks):
+            if pinned:          # immutable after preload: lock-free
+                data = pinned.get(ns + b)
+                if data is not None:
+                    mv[p * B:(p + 1) * B] = data
+                    pinned_hits += 1
+                    continue
+            by_shard.setdefault((ns + b) % S, []).append(p)
+        hits = evictions = 0
+        reserved = []               # (shard, buffer positions to fill)
+        for s, pos in by_shard.items():
+            with self._locks[s]:
+                hit_at, found, miss_at, ev = self._shards[s].lookup_run(
+                    [ns + blocks[p] for p in pos])
+            evictions += ev
+            hits += len(hit_at)
+            for k, data in zip(hit_at, found):
+                p = pos[k]
+                mv[p * B:(p + 1) * B] = data
+            if miss_at:
+                reserved.append((s, [pos[k] for k in miss_at]))
+        miss = [p for _, m in reserved for p in m]
+        if len(reserved) > 1:
+            miss.sort()
+        preads = 0
+        try:
+            if miss:
+                preads, fetched = self._read_runs(key, blocks, miss, mv)
+                for s, m in reserved:
+                    with self._locks[s]:
+                        self._shards[s].fill([ns + blocks[p] for p in m],
+                                             [fetched[p] for p in m])
+        except BaseException:
+            for s, m in reserved:
+                with self._locks[s]:
+                    self._shards[s].release([ns + blocks[p] for p in m])
+            raise
+        misses, nbytes = len(miss), len(miss) * B
+        with self._stat_lock:
+            self._requests += requests
+            self._block_fetches += misses
+            self._bytes_fetched += nbytes
+            self._pinned_hits += pinned_hits
+            self._read_totals["preads"] += preads
+        self._current_ctx().add(
+            requests=requests, hits=hits + pinned_hits, misses=misses,
+            block_fetches=misses, bytes_fetched=nbytes, evictions=evictions,
+            preads=preads)
+        return buf
+
+    def _read_runs(self, key: str, blocks: list, miss: list,
+                   mv: memoryview) -> tuple[int, dict]:
+        """Read the blocks at ascending buffer positions ``miss`` into
+        ``mv``, one ``_attempt`` per run of consecutive blocks.  A run
+        that fails is billed as a failed first attempt of a block read
+        (its fault kind and one retry) and read again block by block
+        through ``_fetch``, under the retry policy.  Returns the
+        ``pread``s issued, but for the retries ``_fetch`` bills itself,
+        and each position's payload."""
+        B = self.block_bytes
+        spans = obs_session.tracing()
+        batch = self._current_ctx().batch if spans else None
+        runs: list[list[int]] = []          # [first position, blocks]
+        for p in miss:
+            if runs and blocks[p] == blocks[runs[-1][0]] + runs[-1][1]:
+                runs[-1][1] += 1
+            else:
+                runs.append([p, 1])
+        fetched: dict[int, bytes] = {}
+        preads = len(runs)
+        for p, n in runs:
+            first = blocks[p]
+            data, kind, _ = self._attempt(key, first, n, 0, spans, batch)
+            if kind is None:
+                mv[p * B:(p + n) * B] = data
+                if n == 1:
+                    fetched[p] = data
+                else:
+                    for k in range(n):
+                        fetched[p + k] = data[k * B:(k + 1) * B]
+                continue
+            self._bill({kind: 1, "retries": 1})
+            time.sleep(self.retry.backoff(key, first, 0))
+            preads += n
+            for k in range(n):
+                data = self._fetch(key, first + k)
+                mv[(p + k) * B:(p + k + 1) * B] = data
+                fetched[p + k] = data
+        return preads, fetched
+
     def _read_array(self, key: str, lo_entry: int, hi_entry: int
                     ) -> np.ndarray:
         dt = self._dtype[key]
-        raw = self._read_range(key, lo_entry * dt.itemsize,
-                               hi_entry * dt.itemsize)
-        return np.frombuffer(raw, dtype=dt)
+        lo, hi = lo_entry * dt.itemsize, hi_entry * dt.itemsize
+        if not self._batched:
+            return np.frombuffer(self._read_range(key, lo, hi), dtype=dt)
+        if hi <= lo:
+            return np.empty(0, dt)
+        B = self.block_bytes
+        first = lo // B
+        buf = self._read_block_list(
+            key, list(range(first, (hi - 1) // B + 1)), 1)
+        off = lo - first * B
+        return buf[off:off + hi - lo].view(dt)
 
     def _block_disjoint_groups(self, los: np.ndarray, his: np.ndarray,
                                max_groups: int):
@@ -768,50 +943,104 @@ class DiskStore:
                                            allowed.size - 1)])
         return np.split(order, pos)
 
-    def _read_group(self, key: str, los, his, idxs) -> list:
-        """Ranges ``idxs`` of array ``key``, in order, as one
-        ``disk.read_group`` span (a pool task, or a serial read on the
-        caller's thread) attributed to the caller's batch."""
+    def _read_group(self, key: str, los, his, idxs
+                    ) -> tuple[np.ndarray, np.ndarray]:
+        """Ranges ``idxs`` of array ``key`` as one buffer and each range's
+        start in it (``_read_blocks``), in one ``disk.read_group`` span (a
+        pool task, or a serial read on the caller's thread) attributed to
+        the caller's batch."""
         batch = (self._current_ctx().batch if obs_session.tracing()
                  else None)
         with obs_session.trace_span("disk.read_group", array=key,
                                     ranges=len(idxs), batch=batch):
-            return [self._read_range(key, int(los[i]), int(his[i]))
-                    for i in idxs]
+            if self._batched:
+                return self._read_blocks(key, los[idxs], his[idxs])
+            parts = [self._read_range(key, int(los[i]), int(his[i]))
+                     for i in idxs]
+            ends = np.cumsum([len(p) for p in parts], dtype=np.int64)
+            return (np.frombuffer(b"".join(parts), np.uint8),
+                    ends - (his[idxs] - los[idxs]).clip(0))
 
-    def _read_many(self, key: str, los, his) -> list:
-        """Bytes of many ranges of array ``key``, in input order.  With a
-        pread pool the ranges are split at disk-block-clean boundaries
-        and the groups read concurrently; all reads stay attributed to
-        the caller's context."""
+    def _read_grouped(self, key: str, los, his, then) -> None:
+        """Read many ranges of array ``key`` in groups (``_read_group``)
+        and call ``then(idxs, buf, starts)`` for each, on the thread that
+        read it, after its read span.  With a pread pool the ranges are
+        split at disk-block-clean boundaries and the groups read
+        concurrently; all reads stay attributed to the caller's
+        context."""
         los = np.asarray(los, np.int64)
         his = np.asarray(his, np.int64)
         n = los.size
-        if self._pool is None or n < 2 * self.io_threads:
-            return self._read_group(key, los, his, range(n))
-        groups = self._block_disjoint_groups(los, his, self.io_threads)
+
+        def task(g):
+            then(g, *self._read_group(key, los, his, g))
+
+        groups = None
+        if self._pool is not None and n >= 2 * self.io_threads:
+            groups = self._block_disjoint_groups(los, his, self.io_threads)
         if groups is None or len(groups) <= 1:
-            return self._read_group(key, los, his, range(n))
-        futs = [(g, self._submit(self._read_group, key, los, his, g))
-                for g in groups]
-        out: list = [None] * n
-        for g, f in futs:
-            for i, buf in zip(g, f.result()):
-                out[int(i)] = buf
+            task(np.arange(n))
+            return
+        for f in [self._submit(task, g) for g in groups]:
+            f.result()
+
+    def _read_many(self, key: str, los, his) -> list:
+        """Bytes of many ranges of array ``key``, in input order."""
+        out: list = [None] * len(los)
+        lens = (np.asarray(his, np.int64) - np.asarray(los, np.int64)).clip(0)
+
+        def keep(g, buf, starts):
+            for i, s, ln in zip(g.tolist(), starts.tolist(),
+                                lens[g].tolist()):
+                out[i] = buf[s:s + ln]
+
+        self._read_grouped(key, los, his, keep)
         return out
+
+    def _gather_rows(self, key: str, ids, per_row: int) -> np.ndarray:
+        """Rows ``ids`` of array ``key``, ``per_row`` entries each, as an
+        ``ids.shape + (per_row,)`` array.  The distinct rows are read in
+        groups and cut from each group's buffer in one gather (a row-wide
+        item at every byte offset), on the thread that read it."""
+        ids = np.asarray(ids)
+        flat = ids.reshape(-1)
+        uniq, inverse = np.unique(flat, return_inverse=True)
+        dt = self._dtype[key]
+        width = per_row * dt.itemsize
+        row = np.dtype((np.void, width))
+        out = np.empty(uniq.size, row)
+        los = uniq.astype(np.int64) * width
+
+        def cut(g, buf, starts):
+            nonlocal out
+            if not g.size:
+                return
+            got = np.ndarray((buf.size - width + 1,), row, buf, 0,
+                             (1,))[starts]
+            if g.size == uniq.size:
+                out = got
+            else:           # ascending rows: a group is a slice of ``out``
+                out[int(g[0]):int(g[0]) + g.size] = got
+
+        self._read_grouped(key, los, los + width, cut)
+        rows = out.view(dt).reshape(uniq.size, per_row)
+        if not np.array_equal(uniq, flat):
+            rows = rows[inverse]
+        return rows.reshape(ids.shape + (per_row,))
 
     def _preload_pinned(self) -> None:
         """Load the pinned hot blocks' payloads eagerly (the §IV-C runtime
         stages its scratchpad before training starts).  The staging reads
         count as block fetches — they are real disk I/O.  After this the
         pinned dict is never mutated, which is what makes the lock-free
-        read in ``_read_range`` safe."""
+        reads (``_read_range``, ``_read_blocks``) safe."""
         ns = self._ns["indices"] * _NS_STRIDE
         for blk in sorted(self._pinned):
             data = self._fetch("indices", blk - ns)
             self._pinned[blk] = data
             self._block_fetches += 1
             self._bytes_fetched += len(data)
+            self._read_totals["preads"] += 1
 
     # -- GraphStore access methods -------------------------------------------
     def neighbors(self, u: int) -> np.ndarray:
@@ -853,31 +1082,15 @@ class DiskStore:
         return out
 
     def gather_features(self, ids) -> np.ndarray:
-        ids = np.asarray(ids)
         if "features" not in self._arrays:
             raise ValueError(f"{self.path}: store has no feature table")
-        F = self.feat_dim
-        dt = self._dtype["features"]
-        uniq, inverse = np.unique(ids.reshape(-1), return_inverse=True)
-        lo = uniq.astype(np.int64) * (F * dt.itemsize)
-        bufs = self._read_many("features", lo, lo + F * dt.itemsize)
-        rows = np.empty((uniq.size, F), np.float32)
-        for j, raw in enumerate(bufs):
-            rows[j] = np.frombuffer(raw, dtype=dt)
-        return rows[inverse].reshape(ids.shape + (F,))
+        return self._gather_rows("features", ids, self.feat_dim)
 
     def gather_labels(self, ids) -> np.ndarray:
-        ids = np.asarray(ids)
         if "labels" not in self._arrays:
             raise ValueError(f"{self.path}: store has no labels")
-        dt = self._dtype["labels"]
-        uniq, inverse = np.unique(ids.reshape(-1), return_inverse=True)
-        lo = uniq.astype(np.int64) * dt.itemsize
-        bufs = self._read_many("labels", lo, lo + dt.itemsize)
-        vals = np.empty(uniq.size, np.int32)
-        for j, raw in enumerate(bufs):
-            vals[j] = np.frombuffer(raw, dtype=dt)[0]
-        return vals[inverse].reshape(ids.shape)
+        ids = np.asarray(ids)
+        return self._gather_rows("labels", ids, 1).reshape(ids.shape)
 
     def gather_edge_blocks(self, blocks, block_e: int) -> np.ndarray:
         """``block_e``-wide int32 chunks of ``indices``, zero-padded past
@@ -959,19 +1172,21 @@ class DiskStore:
         """Raw positional reads of ``indices[positions]`` for sampler
         replay: direct (retry-protected) block preads that bypass the
         page cache entirely — no residency changes, no request/hit/miss
-        accounting.  The oracle replayer must observe the same bytes
-        training will read *without* perturbing the cache it is
-        scheduling."""
+        accounting (its ``preads`` and faults are billed).  The oracle
+        replayer must observe the same bytes training will read *without*
+        perturbing the cache it is scheduling."""
         dt = self._dtype["indices"]
         per = self.block_bytes // dt.itemsize
         pos = np.asarray(positions, np.int64).reshape(-1)
         uniq, inv = np.unique(pos, return_inverse=True)
         out = np.empty(uniq.size, dt)
         blocks = uniq // per
-        for b in np.unique(blocks):
+        distinct = np.unique(blocks)
+        for b in distinct:
             sel = blocks == b
             data = np.frombuffer(self._fetch("indices", int(b)), dtype=dt)
             out[sel] = data[uniq[sel] - int(b) * per]
+        self._bill({"preads": int(distinct.size)})
         return out[inv].reshape(np.shape(positions))
 
     def replay_block_ids(self, *, feature_nodes=None, edge_nodes=None,
@@ -1074,7 +1289,7 @@ class DiskStore:
                     "block_fetches": self._block_fetches,
                     "bytes_fetched": self._bytes_fetched,
                     "hits": hits + self._pinned_hits, "misses": misses,
-                    "evictions": evictions, **self._fault_totals}
+                    "evictions": evictions, **self._read_totals}
 
     def thread_io_counters(self) -> dict:
         """This thread's attribution scope: the installed ``IOContext``

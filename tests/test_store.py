@@ -9,7 +9,7 @@ import pytest
 
 from repro.core import (load_dataset, kronecker_expand, make_loader,
                         rmat_graph, sample_khop)
-from repro.storage import (DiskStore, InMemoryStore, MeasuredEngine,
+from repro.storage import (DiskStore, InMemoryStore, MeasuredEngine, RetrySpec,
                            make_engine, open_store, save_graph)
 from repro.storage.store import MANIFEST
 
@@ -124,6 +124,220 @@ def test_pinned_policy_serves_hot_blocks(small_graph, disk_dir):
     assert after["block_fetches"] == before["block_fetches"]  # pinned hit
     assert after["hits"] > before["hits"]
     st.close()
+
+
+# ---------------------------------------------------------------------------
+# block-batched reads
+# ---------------------------------------------------------------------------
+
+def _feature_blocks(st, ids):
+    """The distinct feature-table blocks rows ``ids`` cover, ascending."""
+    row = st.feat_dim * 4
+    lo = np.asarray(ids, np.int64) * row
+    return np.unique(np.concatenate([np.arange(a, b + 1) for a, b in
+                                     zip(lo // st.block_bytes,
+                                         (lo + row - 1) // st.block_bytes)]))
+
+
+@pytest.mark.parametrize("cache_blocks, below_a_batch",
+                         [(64, True), (2048, False)])
+def test_batched_reads_match_a_sequential_lru_replay(small_graph, disk_dir,
+                                                     cache_blocks,
+                                                     below_a_batch):
+    """Rows stay bit-identical, and the counters are those of an LRU that
+    touches each batch's distinct blocks one at a time in ascending
+    order: below one batch's blocks (64) and above the table (2048)."""
+    from repro.storage import LRUCache
+    g = small_graph
+    st = DiskStore(disk_dir, cache_blocks=cache_blocks, lock_shards=1,
+                   io_threads=1)
+    replay = LRUCache(cache_blocks)
+    ns = st._ns["features"] << 40
+    rng = np.random.default_rng(7)
+    requests = 0
+    try:
+        for _ in range(6):
+            ids = rng.choice(g.num_nodes, 200, replace=False)
+            blocks = _feature_blocks(st, ids)
+            assert (cache_blocks < blocks.size) == below_a_batch
+            np.testing.assert_array_equal(st.gather_features(ids),
+                                          g.features[ids])
+            for b in blocks:
+                replay.access(ns + int(b))
+            requests += ids.size
+        io = st.io_counters()
+    finally:
+        st.close()
+    assert io["requests"] == requests
+    assert io["misses"] == replay.misses
+    assert io["hits"] == replay.hits
+    assert io["evictions"] == replay.evictions
+    assert io["block_fetches"] == replay.misses
+    assert io["bytes_fetched"] == replay.misses * st.block_bytes
+
+
+@pytest.mark.parametrize("block_fails_again", [False, True])
+def test_short_run_read_falls_back_to_per_block_fetch(small_graph, disk_dir,
+                                                      monkeypatch,
+                                                      block_fails_again):
+    """A coalesced read that comes back short is billed as one short read
+    and one retry, as a block read would be, and is read again block by
+    block through ``_fetch``; a block that is short once more there is
+    retried by the policy and billed once more."""
+    g = small_graph
+    st = DiskStore(disk_dir, cache_blocks=1024, io_threads=1,
+                   retry=RetrySpec(backoff_s=0.0))
+    B = st.block_bytes
+    real = os.pread
+    state = {"run": None, "block": 0}
+
+    def flaky(fd, n, off):
+        data = real(fd, n, off)
+        if n > B and state["run"] is None:         # the first run read
+            state["run"] = (off // B, n // B)
+            return data[:n - 100]
+        if block_fails_again and n == B and state["block"] == 0 \
+                and state["run"] is not None \
+                and off // B == state["run"][0] + 1:
+            state["block"] = 1                     # its 2nd block, once
+            return data[:B // 2]
+        return data
+
+    monkeypatch.setattr(os, "pread", flaky)
+    ids = np.arange(0, 40)                         # one run of blocks
+    try:
+        np.testing.assert_array_equal(st.gather_features(ids),
+                                      g.features[ids])
+        io = st.io_counters()
+    finally:
+        st.close()
+    first, n = state["run"]
+    again = int(block_fails_again)
+    assert state["block"] == again and n == io["block_fetches"] > 1
+    assert io["short_reads"] == 1 + again and io["retries"] == 1 + again
+    assert io["io_errors"] == io["corrupt_blocks"] == io["timeouts"] == 0
+    # the run's pread, then one per block, plus the retried block's
+    assert io["preads"] == 1 + n + again
+    assert io["misses"] == io["block_fetches"] == n
+
+
+def test_preads_count_the_runs_of_missed_blocks(small_graph, disk_dir):
+    """One pread per run of consecutive missed blocks: never more than
+    the blocks fetched, and exactly the runs for sorted contiguous rows."""
+    g = small_graph
+    st = DiskStore(disk_dir, cache_blocks=2048, io_threads=1)
+    try:
+        spans = [np.arange(0, 30), np.arange(200, 260), np.arange(700, 701)]
+        ids = np.concatenate(spans)
+        np.testing.assert_array_equal(st.gather_features(ids),
+                                      g.features[ids])
+        io = st.io_counters()
+        assert io["preads"] == len(spans)
+        assert io["block_fetches"] == _feature_blocks(st, ids).size
+        # a second read of the same rows hits: no blocks, no preads
+        st.gather_features(ids)
+        assert st.io_counters()["preads"] == len(spans)
+        # a single range over fresh blocks is one run too
+        row = st.feat_dim
+        before = st.io_counters()
+        np.testing.assert_array_equal(
+            st._read_array("features", 300 * row, 330 * row),
+            g.features[300:330].reshape(-1))
+        io = st.io_counters()
+        assert io["preads"] - before["preads"] == 1
+        assert io["block_fetches"] - before["block_fetches"] == \
+            _feature_blocks(st, np.arange(300, 330)).size > 1
+        rng = np.random.default_rng(3)
+        for _ in range(4):
+            st.gather_features(rng.choice(g.num_nodes, 100, replace=False))
+            io = st.io_counters()
+            assert 0 < io["preads"] <= io["block_fetches"]
+    finally:
+        st.close()
+
+
+def test_a_claimed_slot_is_a_miss_until_filled(small_graph, disk_dir):
+    """A block one batched read has claimed but not yet filled is a miss
+    to another, who reads it and fills the slot; the first reader's own
+    fill then leaves the slot as it is."""
+    from repro.storage.blockdev import RESERVED
+    g = small_graph
+    st = DiskStore(disk_dir, cache_blocks=8, lock_shards=1, io_threads=1)
+    shard = st._shards[0]
+    bid = st._ns["features"] << 40                 # features block 0
+    try:
+        hit_at, _, miss_at, _ = shard.lookup_run([bid])
+        assert (hit_at, miss_at) == ([], [0])
+        assert shard._od[bid] is RESERVED
+        np.testing.assert_array_equal(
+            st._read_array("features", 0, st.feat_dim), g.features[0])
+        io = st.io_counters()
+        assert io["misses"] == 2 and io["hits"] == 0   # claim + reader
+        assert io["block_fetches"] == 1                # the reader's read
+        filled = shard._od[bid]
+        assert filled is not RESERVED and len(filled) == st.block_bytes
+        shard.fill([bid], [b"stale"])
+        assert shard._od[bid] is filled
+    finally:
+        st.close()
+
+
+def test_concurrent_batched_readers_share_the_cache(small_graph, disk_dir):
+    """Many threads read one store at once, many ranges through the
+    pread pool and single ranges, with the interpreter switching
+    threads as often as it can: rows stay bit-identical, every miss is
+    one fetch, each thread's context is billed exactly its own reads,
+    and no claimed slot is left unfilled."""
+    import sys
+    import threading
+
+    from repro.storage.blockdev import RESERVED
+    g = small_graph
+    st = DiskStore(disk_dir, cache_blocks=32, io_threads=4)
+    errors, ctxs = [], []
+
+    def reader(seed):
+        rng = np.random.default_rng(seed)
+        ctx = st.make_io_context()
+        ctxs.append(ctx)
+        try:
+            with st.io_attribution(ctx):
+                for _ in range(10):
+                    # 256 rows (151 blocks) over a 32-block cache: the
+                    # threads keep claiming and reading the same blocks
+                    ids = rng.choice(256, 48, replace=False)
+                    np.testing.assert_array_equal(st.gather_features(ids),
+                                                  g.features[ids])
+                    for u in rng.choice(256, 4).tolist():
+                        np.testing.assert_array_equal(
+                            st._read_array("features", u * st.feat_dim,
+                                           (u + 1) * st.feat_dim),
+                            g.features[u])
+        except Exception as e:          # reported below, on the main thread
+            errors.append(e)
+
+    switch = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        threads = [threading.Thread(target=reader, args=(s,))
+                   for s in range(12)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=120)
+        assert not any(t.is_alive() for t in threads)
+    finally:
+        sys.setswitchinterval(switch)
+        io = st.io_counters()
+        resident = [v for sh in st._shards for v in sh._od.values()]
+        st.close()
+    assert not errors, errors[0]
+    assert io["block_fetches"] == io["misses"] > 0
+    assert io["bytes_fetched"] == io["block_fetches"] * st.block_bytes
+    assert io["requests"] == 12 * 10 * (48 + 4)
+    for key in ("requests", "block_fetches", "misses", "preads"):
+        assert sum(c.counters()[key] for c in ctxs) == io[key], key
+    assert not any(v is RESERVED for v in resident)
 
 
 # ---------------------------------------------------------------------------
