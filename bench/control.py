@@ -4,7 +4,9 @@ compared with the float32 reference by the run's own numbers.
 
     python3 bench/control.py --workload <cell> --seeds 1,2,3
 
-Variants, each at the cell's own size on the cell's own batches:
+Variants, each at the cell's own size on the cell's own batches (the
+configuration's per-chip batch times the cell's chips), with the loss
+of the configuration's arch (``bench/archs/<arch>.py``):
 
 * ``fp8``: the reference in float8, the precision below the
   configuration's bfloat16 (the control);
@@ -28,11 +30,10 @@ import harness
 import reference
 
 
-def batches(cfg, arrays, seed, steps=harness.CHECK_STEPS):
+def batches(cfg, arrays, seed, batch, steps=harness.CHECK_STEPS):
     out = []
     for i in range(steps):
-        t = reference.targets(seed, i, int(cfg["num_nodes"]),
-                              int(cfg["batch_size"]))
+        t = reference.targets(seed, i, int(cfg["num_nodes"]), batch)
         hops = reference.sample_khop(arrays["indptr"], arrays["indices"], t,
                                      cfg["fanouts"], seed, i)
         out.append(([arrays["features"][h] for h in hops],
@@ -40,22 +41,19 @@ def batches(cfg, arrays, seed, steps=harness.CHECK_STEPS):
     return out
 
 
-def readings(cfg, arrays, seed) -> dict:
+def readings(cfg, model, arrays, seed, batch) -> dict:
     """Each variant's compared numbers against the float32 reference."""
     import jax
 
-    depth = len(cfg["fanouts"])
-    p0 = jax.device_get(reference.init_params(
-        seed, int(cfg["feat_dim"]), int(cfg["hidden"]),
-        int(cfg["n_classes"]), depth))
-    bs = batches(cfg, arrays, seed)
-    opt = cfg["optimizer"]
-    base = reference.train(p0, bs, opt)
+    p0 = jax.device_get(model.init_params(seed, cfg, int(cfg["feat_dim"])))
+    bs = batches(cfg, arrays, seed, batch)
+    opt, loss = cfg["optimizer"], model.loss_fn
+    base = reference.train(p0, bs, opt, loss)
     half = [([f[:f.shape[0] // 2] for f in feats], labels[:labels.shape[0]
                                                           // 2])
             for feats, labels in bs]
-    variants = {"fp8": reference.train(p0, bs, opt, "fp8"),
-                "half_batch": reference.train(p0, half, opt)}
+    variants = {"fp8": reference.train(p0, bs, opt, loss, "fp8"),
+                "half_batch": reference.train(p0, half, opt, loss)}
     return {name: gaps(p0, base, got) for name, got in variants.items()}
 
 
@@ -80,12 +78,14 @@ def main(argv=None) -> int:
     ap.add_argument("--seeds", required=True)
     args = ap.parse_args(argv)
     r = harness.resolve(harness.ROOT, args.workload)
-    harness.check_devices(int(r["cell"]["chips"]), True)
-    arrays = graphgen.load_or_make(r["config"],
-                                   os.path.join(harness.CACHE, "graphs"))
+    chips = int(r["cell"]["chips"])
+    harness.check_devices(chips, True)
+    cfg = r["config"]
+    arrays = graphgen.load_or_make(cfg, os.path.join(harness.CACHE, "graphs"))
     for seed in (int(s) for s in args.seeds.split(",")):
         print(json.dumps({"workload": args.workload, "seed": seed,
-                          **readings(r["config"], arrays, seed)}),
+                          **readings(cfg, r["arch"], arrays, seed,
+                                     int(cfg["batch_size"]) * chips)}),
               flush=True)
     return 0
 

@@ -4,6 +4,12 @@ A cell is an entry of ``BENCHMARK.json``'s ``workloads``.  Everything
 else is found by name:
 
 * its configuration, at the ``file`` the manifest gives it;
+* its model, ``bench/archs/<arch>.py`` for the configuration's ``arch``:
+  ``model(cfg, feat_dim)``, the program's network; ``init_params(seed,
+  cfg, feat_dim)``, the benchmark's weights under the program's leaf
+  names; ``loss_fn(params, hop_feats, labels, precision)``, the plain
+  float32 reference loss; ``matmul_flops(cfg, batch)``, the matmul
+  FLOPs of one step's forward and backward passes;
 * its traffic, ``bench/traffic/<traffic>.json``: the data plane the job
   runs (the pipeline spec, with device caches given as shares of the
   graph), the warm-up steps and the traced steps;
@@ -11,6 +17,9 @@ else is found by name:
   the correctness check compares;
 * each per-layer metric, ``bench/metrics/<metric>.py``: a ``read(ctx)``
   that returns the number or None.
+
+The configuration's ``batch_size`` is per chip: a step trains
+``batch_size`` times the cell's ``chips`` targets.
 
 A run drives the path a user runs: ``build_pipeline`` ->
 ``build_train_step`` -> ``train_loop``, in a closed loop (the next batch
@@ -51,7 +60,8 @@ MISMATCH = 1e30                 # a compared number whose shapes differ
 
 class Fail(Exception):
     """A run that cannot report: no chip, fewer chips than the cell asks
-    for, or a manifest that does not name the cell."""
+    for, a manifest that does not name the cell, or a configuration
+    whose ``arch`` has no module."""
 
 
 # ---------------------------------------------------------------------------
@@ -78,10 +88,11 @@ def resolve(root: str, workload: str) -> dict:
     def applies(m):
         return "workloads" not in m or workload in m["workloads"]
 
+    cfg = load_json(os.path.join(root, confs[cell["config"]]["file"]))
     return {
         "cell": cell,
-        "config": load_json(os.path.join(root,
-                                         confs[cell["config"]]["file"])),
+        "config": cfg,
+        "arch": arch(bench, cfg["arch"]),
         "traffic": load_json(os.path.join(bench, "traffic",
                                           cell["traffic"] + ".json")),
         "limits": load_json(os.path.join(bench, "limits",
@@ -92,14 +103,28 @@ def resolve(root: str, workload: str) -> dict:
     }
 
 
-def reader(metrics_dir: str, name: str):
-    """``bench/metrics/<name>.py``'s ``read``."""
-    path = os.path.join(metrics_dir, name + ".py")
+def load_module(prefix: str, path: str):
+    name = os.path.splitext(os.path.basename(path))[0]
     spec = importlib.util.spec_from_file_location(
-        "bench_metric_" + name.replace(".", "_").replace("-", "_"), path)
+        prefix + name.replace(".", "_").replace("-", "_"), path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return mod.read
+    return mod
+
+
+def reader(metrics_dir: str, name: str):
+    """``bench/metrics/<name>.py``'s ``read``."""
+    return load_module("bench_metric_",
+                       os.path.join(metrics_dir, name + ".py")).read
+
+
+def arch(bench: str, name: str):
+    """The module ``bench/archs/<name>.py`` of a configuration's
+    ``arch``."""
+    path = os.path.join(bench, "archs", name + ".py")
+    if not os.path.isfile(path):
+        raise Fail(f"no model for arch {name!r}: {path} is missing")
+    return load_module("bench_arch_", path)
 
 
 # ---------------------------------------------------------------------------
@@ -160,14 +185,14 @@ class Feed:
 
 
 def pipeline_dict(cfg: dict, traffic: dict, seed: int, g, store_dir: str,
-                  ops) -> dict:
+                  ops, chips: int) -> dict:
     """The traffic's pipeline spec, completed from the configuration:
-    batch, fanouts, seed, the store's directory, and device cache sizes
-    from their shares of the graph."""
+    batch (per chip, times the chips), fanouts, seed, the store's
+    directory, and device cache sizes from their shares of the graph."""
     from repro.kernels.neighbor_sample import edge_block_count
 
     spec = json.loads(json.dumps(traffic["pipeline"]))
-    spec["batch_size"] = int(cfg["batch_size"])
+    spec["batch_size"] = int(cfg["batch_size"]) * chips
     spec["seed"] = int(seed)
     spec["sampler"] = {"family": "khop", "fanouts": list(cfg["fanouts"])}
     if spec.get("store", {}).get("kind") == "disk":
@@ -223,7 +248,8 @@ def run_cell(root: str, workload: str, seed: int, seconds: float,
     compared numbers last, under ``checks``."""
     t_start = time.perf_counter() if t_start is None else t_start
     r = resolve(root, workload)
-    cell, cfg, traffic = r["cell"], r["config"], r["traffic"]
+    cell, cfg, traffic, model = (r["cell"], r["config"], r["traffic"],
+                                 r["arch"])
     chips = int(cell["chips"])
     devs = check_devices(chips, require_chip)
 
@@ -235,7 +261,7 @@ def run_cell(root: str, workload: str, seed: int, seconds: float,
     jax.monitoring.register_event_duration_secs_listener(compiles)
 
     from repro import core
-    from repro.core import CSRGraph, GNNConfig, GraphSAGE, PipelineSpec
+    from repro.core import CSRGraph, PipelineSpec
     from repro.distributed.sharding import ShardingRules
     from repro.kernels import ops
     from repro.launch.mesh import make_mesh
@@ -247,10 +273,8 @@ def run_cell(root: str, workload: str, seed: int, seconds: float,
                  name=cfg["name"])
     store_dir = os.path.join(cache_dir, "stores", graphgen.graph_key(cfg))
     spec = PipelineSpec.from_dict(
-        pipeline_dict(cfg, traffic, seed, g, store_dir, ops))
+        pipeline_dict(cfg, traffic, seed, g, store_dir, ops, chips))
     mesh = make_mesh((chips, 1), ("data", "model"))
-    fanouts = tuple(cfg["fanouts"])
-    depth = len(fanouts)
     opt_cfg = cfg["optimizer"]
     window_pick = CHECK_STEPS + int(traffic["warmup_steps"]) \
         + seed % WINDOW_SAMPLES
@@ -259,17 +283,14 @@ def run_cell(root: str, workload: str, seed: int, seconds: float,
     try:
         feed = Feed(pipe, keep_host=range(CHECK_STEPS),
                     keep_device=[window_pick])
-        gnn = GraphSAGE(GNNConfig(feat_dim=g.feat_dim, hidden=cfg["hidden"],
-                                  n_classes=cfg["n_classes"],
-                                  fanouts=fanouts, aggregator="mean"))
+        net = model.model(cfg, g.feat_dim)
         opt = adamw(opt_cfg["lr"], b1=opt_cfg["b1"], b2=opt_cfg["b2"],
                     eps=opt_cfg["eps"],
                     weight_decay=opt_cfg["weight_decay"],
                     max_grad_norm=opt_cfg["max_grad_norm"])
-        step = core.build_train_step(feed, gnn, opt, mesh,
+        step = core.build_train_step(feed, net, opt, mesh,
                                      ShardingRules.default())
-        params = reference.init_params(seed, g.feat_dim, cfg["hidden"],
-                                       cfg["n_classes"], depth)
+        params = model.init_params(seed, cfg, g.feat_dim)
         params0 = jax.device_get(params)
         state = {"params": params, "opt": opt.init(params),
                  "step": jnp.zeros((), jnp.int32)}
@@ -352,7 +373,8 @@ def run_cell(root: str, workload: str, seed: int, seconds: float,
         f"{window_s:.3f} s, {steps} steps, compiles in window "
         f"{window_compiles}")
 
-    ctx = Context(cfg=cfg, traffic=traffic, chips=chips, steps=steps,
+    ctx = Context(cfg=cfg, arch=model, traffic=traffic, chips=chips,
+                  steps=steps,
                   window_s=window_s, batch=spec.batch_size,
                   compile_s=compile_setup_s, wait_s=wait_s,
                   uniq_rows=uniq_rows, counters0=counters0,
@@ -384,8 +406,8 @@ def run_cell(root: str, workload: str, seed: int, seconds: float,
             result_metrics[m["name"]] = {"value": e2e[m["name"]],
                                          "unit": m["unit"]}
 
-    checks = check(cfg, arrays, seed, params0, probe, loss_values, kept,
-                   opt_cfg, r["limits"])
+    checks = check(cfg, model, arrays, seed, params0, probe, loss_values,
+                   kept, opt_cfg, r["limits"])
     failed = sum(not math.isfinite(v) for v in loss_values.values())
     correct = all(c["value"] <= c["limit"] for c in checks.values()) \
         and failed == 0
@@ -425,36 +447,37 @@ class Context:
             return None
         return b - a
 
-    def per_step(self, seconds: float) -> float:
-        """Device seconds summed over chips -> ms per step per chip."""
-        return seconds * 1e3 / self.steps / self.chips
+    def per_step(self, by_chip: dict) -> float:
+        """Device seconds by chip -> ms per step on the slowest chip."""
+        return max(by_chip.values()) * 1e3 / self.steps
 
     def roofline(self, kernel: str, bytes_per_step: float):
         """Percent of its roofline a kernel reached in the traced window:
-        the least time its bytes need at the chip's HBM bandwidth over
-        its device time, per step.  None where the trace holds no such
-        kernel."""
+        the least time a chip's share of its bytes needs at the chip's HBM
+        bandwidth over the kernel's device time per step on the slowest
+        chip.  None where the trace holds no such kernel."""
         if self.trace is None or self.peak is None or self.steps == 0:
             return None
-        ns = sum(o.dur for o in self.ops if o.kernel == kernel)
-        if ns <= 0:
+        by_chip = tracereduce.ns_by_chip(
+            o for o in self.ops if o.kernel == kernel)
+        if not by_chip:
             return None
-        seconds = ns / 1e9 / self.steps / self.chips
-        return 100.0 * bytes_per_step / self.peak["hbm_bytes_per_s"] \
-            / seconds
+        seconds = max(by_chip.values()) / 1e9 / self.steps
+        return 100.0 * bytes_per_step / self.chips \
+            / self.peak["hbm_bytes_per_s"] / seconds
 
 
 # ---------------------------------------------------------------------------
 # the check against the plain reference
 # ---------------------------------------------------------------------------
 
-def check(cfg, arrays, seed, params0, probe, losses, kept, opt_cfg,
+def check(cfg, model, arrays, seed, params0, probe, losses, kept, opt_cfg,
           limits) -> dict:
     """Every compared number beside its limit.  Sampled ids, features
     and labels of the first ``CHECK_STEPS`` batches and of one window
     batch are compared exactly; the losses of the first steps, the first
     gradient as the optimizer got it and the parameters' change over the
-    first steps against ``reference.train``."""
+    first steps against ``reference.train`` of the arch's ``loss_fn``."""
     import jax
 
     fanouts = tuple(cfg["fanouts"])
@@ -487,7 +510,8 @@ def check(cfg, arrays, seed, params0, probe, losses, kept, opt_cfg,
 
     with jax.default_matmul_precision("highest"):
         ref_losses, ref_g0, ref_p3 = reference.train(
-            params0, [ref_batches[i] for i in range(CHECK_STEPS)], opt_cfg)
+            params0, [ref_batches[i] for i in range(CHECK_STEPS)], opt_cfg,
+            model.loss_fn)
     prog_g0 = {k: np.asarray(v) / (1 - opt_cfg["b1"])
                for k, v in probe["m0"].items()}
     gnorm = reference.leaf_norms(ref_g0)

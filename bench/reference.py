@@ -1,7 +1,7 @@
-"""The plain reference: GraphSAGE-mean training in float32, written from
-the published equations (Hamilton et al. 2017, Algorithm 1 with the
-mean aggregator) and the configuration's optimizer, independent of the
-program under test.  It imports nothing of the program.
+"""The plain reference: what every model's float32 training shares,
+independent of the program under test.  It imports nothing of the
+program.  Each architecture's own forward pass and loss live in
+``bench/archs/<arch>.py``, written from its published equations.
 
 What it reproduces, given the seed:
 
@@ -13,11 +13,13 @@ What it reproduces, given the seed:
   ``indptr[node] + word % d`` of its neighbour list (a node of degree 0
   picks itself);
 * the features and labels of the sampled ids, read from the graph;
-* three steps of training: forward, loss, gradients, global-norm
+* the weights, from the seed's weight stream (``init_weights``);
+* three steps of training: the arch's loss and its gradients, global-norm
   clipping and AdamW, all in float32 at ``highest`` matmul precision.
 
 ``precision="fp8"`` rounds every matmul operand and every layer's input
-to float8 (e4m3) and back, the control that must fail the comparison.
+to float8 (e4m3) and back (``rounded``, ``matmul``), the control that
+must fail the comparison.
 """
 
 from __future__ import annotations
@@ -53,19 +55,11 @@ def sample_khop(indptr: np.ndarray, indices: np.ndarray, tgt: np.ndarray,
     return [h.astype(np.int32) for h in hops]
 
 
-def init_params(seed: int, feat_dim: int, hidden: int, n_classes: int,
-                depth: int) -> dict:
+def init_weights(seed: int, shapes: dict) -> dict:
     """The benchmark's weights, made on the device in one jitted call:
-    weights normal with standard deviation 1/sqrt(fan_in), biases 0."""
-    shapes = {}
-    d_in = feat_dim
-    for l in range(depth):
-        shapes[f"l{l}_self"] = (d_in, hidden)
-        shapes[f"l{l}_neigh"] = (d_in, hidden)
-        shapes[f"l{l}_bias"] = (hidden,)
-        d_in = hidden
-    shapes["cls"] = (d_in, n_classes)
-    shapes["cls_bias"] = (n_classes,)
+    leaf ``i`` of ``shapes`` in sorted order is normal from
+    ``fold_in(weight key, i)`` with standard deviation 1/sqrt(fan_in) where
+    it is a matrix, and 0 where it is a vector."""
 
     @jax.jit
     def make(key):
@@ -82,40 +76,20 @@ def init_params(seed: int, feat_dim: int, hidden: int, n_classes: int,
     return make(jax.random.fold_in(jax.random.key(seed), WEIGHT_STREAM))
 
 
-def _round(x, precision: str):
+def rounded(x, precision: str):
     if precision == "fp8":
         return x.astype(jnp.float8_e4m3fn).astype(jnp.float32)
     return x
 
 
-def _mm(x, w, precision: str):
-    return jnp.einsum("...f,fg->...g", _round(x, precision),
-                      _round(w, precision),
+def matmul(x, w, precision: str):
+    return jnp.einsum("...f,fg->...g", rounded(x, precision),
+                      rounded(w, precision),
                       precision=jax.lax.Precision.HIGHEST)
 
 
-def forward(params: dict, hop_feats, precision: str = "f32"):
-    """Logits (B, C): layer ``l`` merges hop ``t+1`` into hop ``t`` for
-    every ``t < depth - l``: h' = normalize(relu(h_t W_self +
-    mean(h_{t+1}) W_neigh + b))."""
-    depth = len(hop_feats) - 1
-    h = [_round(jnp.asarray(x, jnp.float32), precision) for x in hop_feats]
-    for l in range(depth):
-        nxt = []
-        for t in range(depth - l):
-            agg = jnp.mean(h[t + 1], axis=-2)
-            z = (_mm(h[t], params[f"l{l}_self"], precision)
-                 + _mm(agg, params[f"l{l}_neigh"], precision)
-                 + params[f"l{l}_bias"])
-            z = jax.nn.relu(z)
-            norm = jnp.sqrt(jnp.sum(z * z, axis=-1, keepdims=True))
-            nxt.append(_round(z / jnp.maximum(norm, 1e-6), precision))
-        h = nxt
-    return _mm(h[0], params["cls"], precision) + params["cls_bias"]
-
-
-def loss_fn(params, hop_feats, labels, precision: str = "f32"):
-    logits = forward(params, hop_feats, precision)
+def cross_entropy(logits, labels):
+    """Mean softmax cross-entropy of (B, C) logits against (B,) labels."""
     lse = jax.scipy.special.logsumexp(logits, axis=-1)
     picked = jnp.take_along_axis(logits, labels[:, None], axis=-1)[:, 0]
     return jnp.mean(lse - picked)
@@ -140,8 +114,10 @@ def adamw_step(params, grads, m, v, step: int, opt: dict):
     return jax.tree.map(upd, params, m, v), m, v, grads
 
 
-def train(params0: dict, batches, opt: dict, precision: str = "f32"):
-    """Train ``len(batches)`` steps from ``params0``.  ``batches`` is a
+def train(params0: dict, batches, opt: dict, loss_fn,
+          precision: str = "f32"):
+    """Train ``len(batches)`` steps of the arch's ``loss_fn(params,
+    hop_feats, labels, precision)`` from ``params0``.  ``batches`` is a
     list of (hop_feats, labels).  Returns (losses, first clipped
     gradient, final params), all on the host."""
     grad_fn = jax.jit(jax.value_and_grad(loss_fn), static_argnums=3)

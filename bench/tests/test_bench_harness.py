@@ -1,7 +1,9 @@
 """The harness on the CPU at a tiny size: it finds a cell that is added
 as files alone, drives a whole run of it, and decides ``correct`` false
-when the timed path is broken underneath; the lower-precision control
-fails the same limits; a run without a chip reports nothing."""
+when the timed path is broken underneath; a model enters as files alone
+too (``sagepool``: an arch module the test writes, over the program's
+pool aggregator); the lower-precision control fails the same limits; a
+run without a chip reports nothing."""
 
 import json
 import os
@@ -29,6 +31,67 @@ LIMITS = {"ids_mismatched": 0, "features_max_abs_diff": 0.0,
           "labels_mismatched": 0, "loss_gap": 1e-3, "grad_norm_gap": 0.08,
           "change_norm_gap": 0.05}
 
+# A model added as files alone: GraphSAGE with the max-pool aggregator
+# (Hamilton et al. 2017, eq. 3), the program's ``aggregator="pool"``,
+# and a float32 reference of its own.
+SAGEPOOL = '''
+import jax
+import jax.numpy as jnp
+
+import reference
+
+
+def model(cfg, feat_dim):
+    from repro.core import GNNConfig, GraphSAGE
+    return GraphSAGE(GNNConfig(feat_dim=feat_dim, hidden=cfg["hidden"],
+                               n_classes=cfg["n_classes"],
+                               fanouts=tuple(cfg["fanouts"]),
+                               aggregator="pool"))
+
+
+def init_params(seed, cfg, feat_dim):
+    shapes, d_in, hid = {}, feat_dim, cfg["hidden"]
+    for l in range(len(cfg["fanouts"])):
+        shapes.update({f"l{l}_self": (d_in, hid), f"l{l}_neigh": (d_in, hid),
+                       f"l{l}_bias": (hid,), f"l{l}_pool_w": (d_in, d_in),
+                       f"l{l}_pool_b": (d_in,)})
+        d_in = hid
+    shapes.update({"cls": (d_in, cfg["n_classes"]),
+                   "cls_bias": (cfg["n_classes"],)})
+    return reference.init_weights(seed, shapes)
+
+
+def loss_fn(params, hop_feats, labels, precision="f32"):
+    hi = jax.lax.Precision.HIGHEST
+    mm = lambda x, w: jnp.einsum("...f,fg->...g", x, w, precision=hi)
+    h = [jnp.asarray(x, jnp.float32) for x in hop_feats]
+    for l in range(len(h) - 1):
+        nxt = []
+        for t in range(len(h) - 1):
+            pool = jax.nn.relu(mm(h[t + 1], params[f"l{l}_pool_w"])
+                               + params[f"l{l}_pool_b"]).max(axis=-2)
+            z = jax.nn.relu(mm(h[t], params[f"l{l}_self"])
+                            + mm(pool, params[f"l{l}_neigh"])
+                            + params[f"l{l}_bias"])
+            norm = jnp.sqrt(jnp.sum(z * z, axis=-1, keepdims=True))
+            nxt.append(z / jnp.maximum(norm, 1e-6))
+        h = nxt
+    return reference.cross_entropy(mm(h[0], params["cls"])
+                                   + params["cls_bias"], labels)
+
+
+def matmul_flops(cfg, batch):
+    rows, fwd, d_in = [batch], 0, cfg["feat_dim"]
+    for f in cfg["fanouts"]:
+        rows.append(rows[-1] * f)
+    for l in range(len(cfg["fanouts"])):
+        live = len(cfg["fanouts"]) - l
+        fwd += sum(2 * rows[t + 1] * d_in * d_in
+                   + 4 * rows[t] * d_in * cfg["hidden"] for t in range(live))
+        d_in = cfg["hidden"]
+    return 3 * (fwd + 2 * batch * d_in * cfg["n_classes"])
+'''
+
 
 @pytest.fixture(scope="module")
 def root(tmp_path_factory):
@@ -51,9 +114,18 @@ def root(tmp_path_factory):
     man["configs"].append({"name": "sage-tiny", "source": "test",
                            "file": "bench/configs/sage-tiny.json",
                            "reduced": [], "why": "test"})
-    for traffic in ("pallas_hbm", "pallas_ooc"):
-        name = "tiny-" + traffic
-        man["workloads"].append({"name": name, "config": "sage-tiny",
+    (root / "bench" / "archs" / "sagepool.py").write_text(SAGEPOOL)
+    (root / "bench" / "configs" / "sagepool-tiny.json").write_text(
+        json.dumps(dict(cfg, name="sagepool-tiny", arch="sagepool",
+                        aggregator="pool")))
+    man["configs"].append({"name": "sagepool-tiny", "source": "test",
+                           "file": "bench/configs/sagepool-tiny.json",
+                           "reduced": [], "why": "test"})
+    for name, config, traffic in [
+            ("tiny-pallas_hbm", "sage-tiny", "pallas_hbm"),
+            ("tiny-pallas_ooc", "sage-tiny", "pallas_ooc"),
+            ("tiny-sagepool", "sagepool-tiny", "pallas_hbm")]:
+        man["workloads"].append({"name": name, "config": config,
                                  "traffic": traffic, "chips": 1,
                                  "why": "test"})
         (root / "bench" / "limits" / f"{name}.json").write_text(
@@ -78,9 +150,14 @@ def test_harness_finds_a_cell_added_as_files(root):
     assert r["limits"] == LIMITS
     assert [m["name"] for m in r["end_to_end"]] == ["targets_per_s",
                                                     "setup_s"]
-    names = [m["name"] for m in r["per_layer"]]
+    # every per-layer metric lists its cells, so a new cell reads none
+    # until an entry of its own names it
+    assert r["per_layer"] == []
+    names = [m["name"] for m in harness.resolve(root,
+                                                "reddit-hbm")["per_layer"]]
     assert "feature_cache_hit_rate" not in names and "compile_s" in names
-    for m in r["per_layer"]:
+    for m in harness.load_json(os.path.join(root,
+                                            "BENCHMARK.json"))["per_layer"]:
         assert callable(harness.reader(r["metrics_dir"], m["name"]))
     with pytest.raises(harness.Fail):
         harness.resolve(root, "no-such-cell")
@@ -161,6 +238,36 @@ FAULTS = {"state_unchanged": (_state_unchanged, "change_norm_gap"),
           "label_altered": (_altered("labels"), "labels_mismatched")}
 
 
+@pytest.mark.parametrize("fault", [None, "half_batch", "state_unchanged"])
+def test_a_model_added_as_files_runs_and_is_checked(root, monkeypatch,
+                                                    fault):
+    """``sagepool`` enters with a config, an arch module, limits and
+    manifest entries: every file the repository's ``bench/`` has is
+    unchanged in the checkout.  A sound run is correct; a run with its
+    state left unchanged or half its batch left out is not."""
+    import filecmp
+    for d, _, files in os.walk(BENCH):
+        if "__pycache__" in d or os.sep + "tests" in d or ".cache" in d:
+            continue
+        for f in files:
+            mine = os.path.join(root, "bench", os.path.relpath(d, BENCH), f)
+            assert filecmp.cmp(os.path.join(d, f), mine, shallow=False), f
+    r = harness.resolve(root, "tiny-sagepool")
+    assert r["arch"].__name__ == "bench_arch_sagepool"
+    if fault is None:
+        out = run(root, "tiny-sagepool")
+        assert out["correct"], out["checks"]
+        assert out["attempted"] > harness.CHECK_STEPS
+        return
+    from repro import core
+    plant, number = FAULTS[fault]
+    plant(core, monkeypatch)
+    out = run(root, "tiny-sagepool")
+    assert not out["correct"]
+    c = out["checks"][number]
+    assert c["value"] > c["limit"], out["checks"]
+
+
 @pytest.mark.parametrize("fault", sorted(FAULTS))
 def test_a_broken_timed_path_is_not_correct(root, monkeypatch, fault):
     from repro import core
@@ -181,9 +288,26 @@ def test_the_lower_precision_control_fails_the_limits():
     cfg = dict(harness.load_json(os.path.join(
         BENCH, "configs", "sage-reddit.json")), num_nodes=5000,
         batch_size=32, graph_seed=5)
-    r = control.readings(cfg, graphgen.make_graph(cfg), SEED)
+    r = control.readings(cfg, harness.arch(BENCH, "graphsage"),
+                         graphgen.make_graph(cfg), SEED, 32)
     limits = harness.load_json(os.path.join(BENCH, "limits",
                                             "reddit-hbm.json"))
+    for variant in ("fp8", "half_batch"):
+        assert any(r[variant][k] > limits[k] for k in r[variant]), r
+
+
+def test_the_control_fails_the_four_chip_cells_limits():
+    """The same at products' widths and depth, against the limits of
+    ``products-mesh4``, whose fp8 control reads nearest its sound runs."""
+    import control
+    import graphgen
+    cfg = dict(harness.load_json(os.path.join(
+        BENCH, "configs", "sage-products.json")), num_nodes=5000,
+        batch_size=8, graph_seed=5)
+    r = control.readings(cfg, harness.arch(BENCH, "graphsage"),
+                         graphgen.make_graph(cfg), SEED, 4 * 8)
+    limits = harness.load_json(os.path.join(BENCH, "limits",
+                                            "products-mesh4.json"))
     for variant in ("fp8", "half_batch"):
         assert any(r[variant][k] > limits[k] for k in r[variant]), r
 

@@ -131,8 +131,8 @@ def test_trace_reduction_on_a_recorded_trace():
                             "jit_step")
     assert s["window_s"] == pytest.approx(100e-9)
     assert s["busy_s"] == pytest.approx(55e-9)       # [5,30) + [50,80)
-    assert s["step_device_s"] == pytest.approx(30e-9)
-    assert s["prep_device_s"] == pytest.approx(35e-9)
+    assert s["step_device_s"] == {0: pytest.approx(30e-9)}
+    assert s["prep_device_s"] == {0: pytest.approx(35e-9)}
     assert s["device_ops"][0] == ["jit_step/fusion", pytest.approx(30e-9)]
     assert ["jit_prepare/neighbor_sample", pytest.approx(15e-9)] in \
         s["device_ops"]
@@ -186,3 +186,94 @@ def test_trace_load_reads_host_spans_of_a_real_profile(tmp_path):
     assert tr.ops == []
     lo, hi = tr.window()
     assert hi > lo
+
+
+def test_collective_ops_by_opcode_and_name():
+    coll = ["%all-reduce.3 = f32[8]{0} all-reduce(f32[8]{0} %x), "
+            "replica_groups={{0,1,2,3}}, to_apply=%add",
+            "%all-reduce-start.1 = (f32[8]{0}, f32[8]{0}) "
+            "all-reduce-start(f32[8]{0} %y)",
+            "%all-reduce-done.1 = f32[8]{0} all-reduce-done((f32[8]{0}, "
+            "f32[8]{0}) %all-reduce-start.1)",
+            "%all-gather.2 = f32[32]{0} all-gather(f32[8]{0} %z)",
+            "%reduce-scatter.7 = f32[2]{0} reduce-scatter(f32[8]{0} %z)",
+            "%all-to-all = f32[8]{0} all-to-all(f32[8]{0} %z)",
+            "%collective-permute-done.4 = f32[8]{0} "
+            "collective-permute-done(f32[8]{0} %s)",
+            "%all-gather-fusion.2 = f32[32,1]{1,0:T(8,128)} fusion(...)"]
+    other = ["%fusion.2 = f32[8]{0} fusion(f32[8]{0} %a), kind=kLoop, "
+             "calls=%all_reduce_like",
+             "%reduce.3 = f32[]{:T(128)} reduce(f32[8]{0} %a, f32[] %c)",
+             "%copy-done", "%gather.1 = f32[8,4]{1,0} gather(...)",
+             "%neighbor_sample.2 = s32[8,4]{1,0} custom-call(...)"]
+    assert Op(0, coll[0], "", 0, 1).opcode == "all-reduce"
+    assert Op(0, coll[1], "", 0, 1).opcode == "all-reduce-start"
+    assert Op(0, other[0], "", 0, 1).opcode == "fusion"
+    assert Op(0, "%copy-done", "", 0, 1).opcode == ""
+    assert all(Op(0, n, "", 0, 1).collective for n in coll)
+    assert not any(Op(0, n, "", 0, 1).collective for n in other)
+
+
+def _four_chips():
+    """A 100 ns window on four chips, one train step each: chip 2 is the
+    slowest, with the longest step, data plane and all-reduce."""
+    ops = []
+    for chip, (prep, ar, step) in enumerate([(20, 10, 30), (22, 10, 30),
+                                             (30, 25, 40), (21, 5, 30)]):
+        ops += [Op(chip, "%fusion.1 = f32[64]{0} fusion(...)",
+                   "jit_prepare", 0, prep),
+                Op(chip, "%all-reduce.2 = f32[64]{0} all-reduce(...)",
+                   "jit_prepare", prep, ar),
+                Op(chip, "%fusion.3 = f32[8,16]{1,0} fusion(...)",
+                   "jit_step", 50, step)]
+    spans = [Span("window", 0, 100), Span("get_batch", 0, 50),
+             Span("train_step", 50, 100)]
+    return Trace(ops=ops, spans=spans, chips=4)
+
+
+def test_four_device_planes_read_per_chip_and_the_slowest():
+    import harness
+    s = tracereduce.summary(_four_chips(),
+                            step_module=lambda m: m == "jit_step")
+    assert s["busy_by_chip"] == {0: pytest.approx(60e-9),
+                                 1: pytest.approx(62e-9),
+                                 2: pytest.approx(90e-9),
+                                 3: pytest.approx(56e-9)}
+    assert s["busy_s"] == pytest.approx((60 + 62 + 90 + 56) / 4 * 1e-9)
+    assert s["step_device_s"][2] == pytest.approx(40e-9)
+    assert s["prep_device_s"] == {0: pytest.approx(30e-9),
+                                  1: pytest.approx(32e-9),
+                                  2: pytest.approx(55e-9),
+                                  3: pytest.approx(26e-9)}
+    assert s["collective_s"] == {0: pytest.approx(10e-9),
+                                 1: pytest.approx(10e-9),
+                                 2: pytest.approx(25e-9),
+                                 3: pytest.approx(5e-9)}
+    tr = _four_chips()
+    ctx = harness.Context(trace=tr, ops=tr.ops_in(0, 100), device=s,
+                          steps=1, chips=4)
+    read = lambda m: harness.reader(  # noqa: E731
+        os.path.join(os.path.dirname(tracereduce.__file__), "metrics"),
+        m)(ctx)
+    assert read("train_step_ms") == pytest.approx(40e-6)
+    assert read("prep_device_ms") == pytest.approx(55e-6)
+    assert read("collective_ms") == pytest.approx(25e-6)
+    assert read("device_idle_share") == pytest.approx(10.0)
+
+
+def test_one_device_plane_reads_as_before():
+    """The recorded one-chip trace: the readers give what the sums over
+    its one chip give, and no collective."""
+    import harness
+    tr = _recorded()
+    s = tracereduce.summary(tr, step_module=lambda m: m == "jit_step")
+    ctx = harness.Context(trace=tr, ops=tr.ops_in(0, 100), device=s,
+                          steps=2, chips=1)
+    read = lambda m: harness.reader(  # noqa: E731
+        os.path.join(os.path.dirname(tracereduce.__file__), "metrics"),
+        m)(ctx)
+    assert read("train_step_ms") == pytest.approx(30e-9 * 1e3 / 2)
+    assert read("prep_device_ms") == pytest.approx(35e-9 * 1e3 / 2)
+    assert read("device_idle_share") == pytest.approx(45.0)
+    assert read("collective_ms") is None
+    assert s["collective_s"] == {0: 0.0}

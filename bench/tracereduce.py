@@ -10,6 +10,10 @@ executed program (``jit_step(12)``); an op belongs to the module span it
 starts in.  Host planes hold the benchmark's own ``TraceAnnotation``
 spans, on the same clock.
 
+On several chips the device numbers are kept per chip, and the metrics
+read the slowest: in a data-parallel step every chip waits for the last
+one at the collective.
+
 Everything here works on plain tuples, so a test can feed it a small
 recorded trace without a chip.
 """
@@ -20,10 +24,18 @@ import bisect
 import dataclasses
 import glob
 import os
+import re
 
 OPS_LINE = "XLA Ops"
 MODULES_LINE = "XLA Modules"
 DEVICE_PREFIX = "/device:TPU:"
+# HLO opcodes of the exchanges between chips; the async forms
+# (``all-reduce-start``, ``-done``) and fusions XLA names after them
+# (``all-gather-fusion``) share these prefixes.
+COLLECTIVES = ("all-reduce", "all-gather", "reduce-scatter", "all-to-all",
+               "ragged-all-to-all", "collective-permute",
+               "collective-broadcast")
+_OPCODE = re.compile(r"(?:^|\s)([a-z][a-z0-9_-]*)\(")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -47,6 +59,20 @@ class Op:
         short = self.name.split(" = ", 1)[0].strip().lstrip("%")
         head, _, tail = short.rpartition(".")
         return head if head and tail.isdigit() else short
+
+    @property
+    def opcode(self) -> str:
+        """The instruction's HLO opcode: ``%x.3 = f32[8]{0} all-reduce(...)``
+        -> ``all-reduce``; '' where the text holds none."""
+        _, eq, rhs = self.name.partition(" = ")
+        m = _OPCODE.search(rhs) if eq else None
+        return m.group(1) if m else ""
+
+    @property
+    def collective(self) -> bool:
+        """An exchange between chips, by its opcode or its name."""
+        return any(x.startswith(COLLECTIVES)
+                   for x in (self.opcode, self.kernel))
 
 
 def module_name(span_name: str) -> str:
@@ -93,6 +119,14 @@ def merge(intervals) -> list[tuple[float, float]]:
         else:
             out.append([s, e])
     return [(s, e) for s, e in out]
+
+
+def ns_by_chip(ops) -> dict:
+    """Device ns of ``ops`` summed per chip; chips with none are absent."""
+    out: dict[int, float] = {}
+    for o in ops:
+        out[o.chip] = out.get(o.chip, 0.0) + o.dur
+    return out
 
 
 def busy_ns(ops, chip: int) -> float:
@@ -187,14 +221,22 @@ def summary(tr: Trace, *, step_module,
     """The traced window's device numbers.
 
     ``step_module(module) -> bool`` says which ops belong to the train
-    step.  Returns busy and window seconds (busy averaged over chips),
-    device seconds in and outside the train step (summed over chips),
-    and the breakdown: the ops that took most time, and the longest idle
-    gaps named by what the host did in most of each."""
+    step.  Returns busy and window seconds (busy averaged over chips);
+    per chip, busy seconds and device seconds in and outside the train
+    step and in collectives; and the breakdown: the ops that took most
+    time, and the longest idle gaps named by what the host did in most
+    of each."""
     lo, hi = tr.window()
     ops = tr.ops_in(lo, hi)
     chips = sorted({o.chip for o in ops}) or [0]
-    busy = sum(busy_ns(ops, c) for c in chips) / len(chips)
+    busy_by_chip = {c: busy_ns(ops, c) / 1e9 for c in chips}
+    step_ns = ns_by_chip(o for o in ops if step_module(o.module))
+    prep_ns = ns_by_chip(o for o in ops if not step_module(o.module))
+    coll_ns = ns_by_chip(o for o in ops if o.collective)
+
+    def by_chip(ns):
+        return {c: ns.get(c, 0.0) / 1e9 for c in chips}
+
     by_op: dict[str, float] = {}
     for o in ops:
         key = f"{o.module}/{o.kernel}"
@@ -203,12 +245,13 @@ def summary(tr: Trace, *, step_module,
     for c in chips:
         for s, e in idle_gaps(ops, c, lo, hi):
             gaps.append((gap_label(tr.spans, s, e, labels), e - s))
-    step_ns = sum(o.dur for o in ops if step_module(o.module))
     return {
         "window_s": (hi - lo) / 1e9,
-        "busy_s": busy / 1e9,
-        "step_device_s": step_ns / 1e9,
-        "prep_device_s": sum(o.dur for o in ops) / 1e9 - step_ns / 1e9,
+        "busy_s": sum(busy_by_chip.values()) / len(chips),
+        "busy_by_chip": busy_by_chip,
+        "step_device_s": by_chip(step_ns),
+        "prep_device_s": by_chip(prep_ns),
+        "collective_s": by_chip(coll_ns),
         "device_ops": [[k, v / 1e9] for k, v in
                        sorted(by_op.items(), key=lambda kv: -kv[1])[:top]],
         "idle_gaps": [[k, v / 1e9] for k, v in
